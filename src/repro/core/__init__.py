@@ -8,7 +8,6 @@
 - :mod:`repro.core.stencil`           paper applications (Heat2D / RK3 / HPCCG) on the core
 """
 
-from repro import compat  # noqa: F401  (jax version shims)
 from repro.core.domain import (Box, Domain, SubDomain, decompose_grid,
                                halo_cells, interior_boxes)
 

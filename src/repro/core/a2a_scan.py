@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat  # noqa: F401  (jax version shims)
-
 
 def a2a_scan(x: jax.Array,
              compute_fn: Callable[[jax.Array, int], jax.Array],

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax version shims)
 from repro.core.domain import part_extents
 from repro.core.halo import (_norm_subn, exchange_halo, halo_scan_nd,
                              multi_dim_stencil, pad_with_halo,

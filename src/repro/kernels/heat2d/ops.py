@@ -54,7 +54,6 @@ def heat2d_sweep_sharded(u: jax.Array, mesh, axis_names=("rows", "cols"),
                             halo=(north, south, west, east))
 
     # replication check off: jax has no replication rule for pallas_call yet
-    # (modern `check_vma` spelling; compat maps it to check_rep on 0.4.x)
     f = jax.shard_map(local, mesh=mesh, in_specs=P(ar, ac),
                       out_specs=P(ar, ac), check_vma=False)
     return jax.jit(f)(u)

@@ -24,7 +24,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, h_scr, *, q: int, nc: int):
 
     @pl.when(ic == 0)
     def _init():
-        h_scr[...] = h0_ref[...].astype(jnp.float32)       # (1, w)
+        h_scr[...] = h0_ref[0].astype(jnp.float32)         # (1, w)
 
     def body(t, h):
         a_t = a_ref[0, t, :].astype(jnp.float32)
@@ -38,7 +38,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, h_scr, *, q: int, nc: int):
 
     @pl.when(ic == nc - 1)
     def _done():
-        hlast_ref[...] = h.astype(hlast_ref.dtype)
+        hlast_ref[0] = h.astype(hlast_ref.dtype)
 
 
 def lru_scan_pallas(a: jax.Array, b: jax.Array, h0: Optional[jax.Array] = None,
@@ -52,8 +52,11 @@ def lru_scan_pallas(a: jax.Array, b: jax.Array, h0: Optional[jax.Array] = None,
             f"lru_scan_pallas: sequence length {l} is not divisible by "
             f"chunk={chunk} (a.shape={a.shape})")
     nc = l // chunk
-    if h0 is None:
-        h0 = jnp.zeros((bsz, w), jnp.float32)
+    # h0/h_last travel as (b, 1, w) so each block's last two dims, (1, w),
+    # equal the array's: a (1, w) block of a (b, w) array is refused by the
+    # TPU lowering whenever b > 1
+    h0 = (jnp.zeros((bsz, 1, w), jnp.float32) if h0 is None
+          else h0.reshape(bsz, 1, w))
 
     kernel = functools.partial(_kernel, q=chunk, nc=nc)
     h, hlast = pl.pallas_call(
@@ -62,17 +65,17 @@ def lru_scan_pallas(a: jax.Array, b: jax.Array, h0: Optional[jax.Array] = None,
         in_specs=[
             pl.BlockSpec((1, chunk, w), lambda ib, ic: (ib, ic, 0)),
             pl.BlockSpec((1, chunk, w), lambda ib, ic: (ib, ic, 0)),
-            pl.BlockSpec((1, w), lambda ib, ic: (ib, 0)),
+            pl.BlockSpec((1, 1, w), lambda ib, ic: (ib, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, w), lambda ib, ic: (ib, ic, 0)),
-            pl.BlockSpec((1, w), lambda ib, ic: (ib, 0)),
+            pl.BlockSpec((1, 1, w), lambda ib, ic: (ib, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, l, w), b.dtype),
-            jax.ShapeDtypeStruct((bsz, w), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, 1, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, w), jnp.float32)],
         interpret=interpret,
     )(a, b, h0)
-    return h, hlast
+    return h, hlast.reshape(bsz, w)

@@ -94,9 +94,7 @@ def _layer_period(arch: str) -> int:
 def _extract(compiled, lowered_text: Optional[str] = None) -> Dict[str, Any]:
     from repro.analysis.hlo import count_ops, parse_collectives
 
-    from repro.compat import cost_analysis_dict
-
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     coll = parse_collectives(text)

@@ -202,7 +202,10 @@ def fsdp_init_state(model: LanguageModel, parallel: ParallelConfig, mesh,
             zeros = jax.jit(functools.partial(group_zeros, g),
                             out_shardings=sharding)
             m[g.key], v[g.key] = zeros(), zeros()
-    opt = {"m": m, "v": v, "step": jnp.zeros((), jnp.int32)}
+    # the step counter is placed as the step's output is (replicated on the
+    # mesh): an uncommitted scalar would key a second compile of step 2
+    step = jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(mesh, P()))
+    opt = {"m": m, "v": v, "step": step}
     return flat, opt, layout
 
 

@@ -57,9 +57,12 @@ def main(argv: Optional[list] = None) -> int:
                     help="fault-tolerant restarts budget (runtime.ft)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import (make_production_mesh,
                                    make_single_device_mesh)
     from repro.runtime.trainer import Trainer
+
+    enable_compile_cache()
 
     mesh = None
     if args.mesh == "single-device":

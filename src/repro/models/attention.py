@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat  # noqa: F401  (jax version shims)
 from repro.config.base import ModelConfig
 from repro.models.layers import ParamSpec, apply_rope, rms_norm
 from repro.sharding.rules import with_logical
@@ -38,7 +37,8 @@ def attention_specs(cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict[str, ParamSpec
                         ("embed", "kv_heads", "head_dim"), dtype),
         "wv": ParamSpec((cfg.d_model, cfg.num_kv_heads, hd),
                         ("embed", "kv_heads", "head_dim"), dtype),
-        "wo": ParamSpec((cfg.num_heads, hd, cfg.d_model), ("heads", "head_dim", "embed"), dtype),
+        "wo": ParamSpec((cfg.num_heads, hd, cfg.d_model), ("heads", "head_dim", "embed"),
+                        dtype, scale=(cfg.num_heads * hd) ** -0.5),  # fan-in h*hd
     }
     if cfg.qk_norm:
         s["q_norm"] = ParamSpec((hd,), (None,), jnp.float32, "ones")

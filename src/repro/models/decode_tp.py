@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from repro.config.base import ModelConfig
 from repro.core import collective_matmul as cm
 from repro.models.attention import _sdpa_dense

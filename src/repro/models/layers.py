@@ -9,6 +9,7 @@ reuse it at every level" discipline HDOT prescribes for domains.
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -53,6 +54,9 @@ def _leaf_paths(tree: PyTree, prefix=()) -> Dict[Tuple, ParamSpec]:
     return out
 
 
+_BATCH_AXES = ("layers", "experts")
+
+
 def init_leaf(key: jax.Array, path: Tuple, spec: ParamSpec) -> jax.Array:
     """Materialize ONE parameter leaf. The leaf's key is derived from its tree
     path rather than traversal order, so initializing any SUBSET of leaves —
@@ -62,8 +66,12 @@ def init_leaf(key: jax.Array, path: Tuple, spec: ParamSpec) -> jax.Array:
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
-    k = jax.random.fold_in(key, hash(path) % (2**31))
-    fan_in = spec.shape[0] if len(spec.shape) > 1 else max(spec.shape[-1], 1)
+    # crc32, not hash(): str hashes are salted per process, and the weights
+    # must depend on `key` alone
+    k = jax.random.fold_in(key, zlib.crc32(repr(path).encode()) % (2**31))
+    # a stacked-layers or experts axis is a batch of leaves, not fan-in
+    dims = [n for n, a in zip(spec.shape, spec.axes) if a not in _BATCH_AXES]
+    fan_in = dims[0] if len(dims) > 1 else max(dims[-1], 1)
     scale = spec.scale if spec.scale is not None else 1.0 / math.sqrt(fan_in)
     n = jax.random.normal(k, spec.shape, jnp.float32)
     # barrier: under jit XLA would merge this scale into normal()'s internal

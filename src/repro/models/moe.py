@@ -19,7 +19,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat  # noqa: F401  (jax version shims)
 from repro.config.base import ModelConfig
 from repro.models.layers import ParamSpec
 from repro.sharding.rules import with_logical

@@ -21,8 +21,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat  # noqa: F401  (backfills lax.axis_size on old jax)
-
 PyTree = Any
 
 

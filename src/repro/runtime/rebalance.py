@@ -23,8 +23,7 @@ Two drivers live here:
   death) reroutes bands via :func:`repro.runtime.ft.reassign_host_shards`.
 
 repro imports stay inside functions: the drill's spawned workers re-import
-this module and must not pay for jax (``repro.core.__init__`` pulls the
-compat shims, which import jax).
+this module and must not pay for jax.
 """
 from __future__ import annotations
 

@@ -104,9 +104,7 @@ def test_layer_extrapolation_exact_on_small_arch(single_mesh):
                  "targets": jax.ShapeDtypeStruct((2, 64), jnp.int32)}
         c = jax.jit(jax.value_and_grad(m.train_loss)).lower(
             m.abstract_params(), batch).compile()
-        from repro.compat import cost_analysis_dict
-
-        return cost_analysis_dict(c)["flops"]
+        return c.cost_analysis()["flops"]
 
     f1, f2, f4 = flops(1), flops(2), flops(4)
     per_layer = f2 - f1

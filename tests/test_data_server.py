@@ -3,7 +3,8 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data.pipeline import SyntheticLMDataset
 

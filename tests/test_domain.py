@@ -4,7 +4,8 @@ boundary/halo accounting must match the paper's published Table 1."""
 from __future__ import annotations
 
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.domain import (Domain, decompose_grid, halo_cells,
                                halo_fraction)

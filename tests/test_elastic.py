@@ -9,7 +9,8 @@ import textwrap
 from pathlib import Path
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.runtime.ft import reassign_host_shards
 

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          bf16_compress, bf16_decompress, ef_compress_update,

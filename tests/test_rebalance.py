@@ -15,7 +15,8 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.cost import CostModel
 from repro.core.domain import (decompose_grid, interior_boxes, interior_cuts,

@@ -5,7 +5,8 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.rules import (ShardingContext, resolve_pspec,
