@@ -70,6 +70,17 @@ from repro.core.domain import interior_boxes
 Axes = Sequence[Tuple[str, int]]
 Decomp = Axes  # deprecated alias, pre-unification spelling
 
+# The HDOT stages, as `jax.named_scope` names: every op of a solver is traced
+# under at most one of them (the innermost wins), so a device trace's op
+# names (`tf_op`) say which stage spent the time. The solvers in
+# core/stencil.py and the reductions in core/reduction.py use the same set.
+FACES = "hdot.faces"          # boundary-face sources and their stencils
+INTERIOR = "hdot.interior"    # interior chunk stencils (two-phase: the block)
+ASSEMBLE = "hdot.assemble"    # concatenates of chunk and face outputs
+EXCHANGE = "hdot.exchange"    # halo slices, ppermutes, pads and stitching
+REDUCE = "hdot.reduce"        # residuals, dot products, their allreduces
+UPDATE = "hdot.update"        # the solver's vector updates and scalars
+
 _DEPRECATION_WARNED: set = set()
 
 
@@ -105,33 +116,36 @@ def exchange_edges(lo_edge: jax.Array, hi_edge: jax.Array, axis_name: str,
     use boundary conditions.
     """
     n = lax.axis_size(axis_name)
-    if n == 1:
-        if periodic:  # wrap around to own edges
-            return hi_edge, lo_edge
-        return jnp.zeros_like(hi_edge), jnp.zeros_like(lo_edge)
-    if periodic:
-        fwd = [(i, (i + 1) % n) for i in range(n)]
-        bwd = [(i, (i - 1) % n) for i in range(n)]
-    else:
-        fwd = [(i, i + 1) for i in range(n - 1)]
-        bwd = [(i, i - 1) for i in range(1, n)]
-    lo_halo = lax.ppermute(hi_edge, axis_name, fwd)
-    hi_halo = lax.ppermute(lo_edge, axis_name, bwd)
-    return lo_halo, hi_halo
+    with jax.named_scope(EXCHANGE):
+        if n == 1:
+            if periodic:  # wrap around to own edges
+                return hi_edge, lo_edge
+            return jnp.zeros_like(hi_edge), jnp.zeros_like(lo_edge)
+        if periodic:
+            fwd = [(i, (i + 1) % n) for i in range(n)]
+            bwd = [(i, (i - 1) % n) for i in range(n)]
+        else:
+            fwd = [(i, i + 1) for i in range(n - 1)]
+            bwd = [(i, i - 1) for i in range(1, n)]
+        lo_halo = lax.ppermute(hi_edge, axis_name, fwd)
+        hi_halo = lax.ppermute(lo_edge, axis_name, bwd)
+        return lo_halo, hi_halo
 
 
 def exchange_halo(u: jax.Array, axis_name: str, width: int, dim: int,
                   periodic: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Returns (lo_halo, hi_halo): the neighbor edges this shard receives."""
-    return exchange_edges(_edge(u, dim, "lo", width), _edge(u, dim, "hi", width),
-                          axis_name, periodic)
+    with jax.named_scope(EXCHANGE):
+        return exchange_edges(_edge(u, dim, "lo", width),
+                              _edge(u, dim, "hi", width), axis_name, periodic)
 
 
 def pad_with_halo(u: jax.Array, axis_name: str, width: int, dim: int,
                   periodic: bool = False) -> jax.Array:
     """Two-phase building block: concat [lo_halo, u, hi_halo] along `dim`."""
-    lo, hi = exchange_halo(u, axis_name, width, dim, periodic)
-    return jnp.concatenate([lo, u, hi], axis=dim)
+    with jax.named_scope(EXCHANGE):
+        lo, hi = exchange_halo(u, axis_name, width, dim, periodic)
+        return jnp.concatenate([lo, u, hi], axis=dim)
 
 
 # --------------------------------------------------------------------------
@@ -183,7 +197,8 @@ def exchange_halo_nd(u: jax.Array, axes: Axes, width: int,
                      ) -> List[Tuple[jax.Array, jax.Array]]:
     """One ppermute pair per decomposed axis; returns [(lo_k, hi_k), ...] in
     `axes` order. Corner ghosts are NOT exchanged."""
-    return [exchange_halo(u, a, width, d, periodic) for a, d in axes]
+    with jax.named_scope(EXCHANGE):
+        return [exchange_halo(u, a, width, d, periodic) for a, d in axes]
 
 
 def pad_with_halo_nd(u: jax.Array, halos, width: int,
@@ -191,14 +206,15 @@ def pad_with_halo_nd(u: jax.Array, halos, width: int,
     """Assemble the corner-free padded block: face halos on every decomposed
     dim, ZEROS in the corner ghosts (star stencils never read them)."""
     out = u
-    for k in reversed(range(len(dims))):
-        lo, hi = halos[k]
-        pads = [(0, 0)] * u.ndim
-        for j in range(k + 1, len(dims)):
-            pads[dims[j]] = (width, width)
-        lo = jnp.pad(lo, pads)
-        hi = jnp.pad(hi, pads)
-        out = jnp.concatenate([lo, out, hi], axis=dims[k])
+    with jax.named_scope(EXCHANGE):
+        for k in reversed(range(len(dims))):
+            lo, hi = halos[k]
+            pads = [(0, 0)] * u.ndim
+            for j in range(k + 1, len(dims)):
+                pads[dims[j]] = (width, width)
+            lo = jnp.pad(lo, pads)
+            hi = jnp.pad(hi, pads)
+            out = jnp.concatenate([lo, out, hi], axis=dims[k])
     return out
 
 
@@ -240,9 +256,10 @@ def _faces_nd(u: jax.Array, halos,
               stencil_fn: Callable[[jax.Array], jax.Array], width: int,
               dims: Sequence[int]) -> List[Tuple[jax.Array, jax.Array]]:
     """The 2·N boundary-face tasks — the only consumers of the halos."""
-    return [(stencil_fn(_face_src_nd(u, halos, k, "lo", width, dims)),
-             stencil_fn(_face_src_nd(u, halos, k, "hi", width, dims)))
-            for k in range(len(dims))]
+    with jax.named_scope(FACES):
+        return [(stencil_fn(_face_src_nd(u, halos, k, "lo", width, dims)),
+                 stencil_fn(_face_src_nd(u, halos, k, "hi", width, dims)))
+                for k in range(len(dims))]
 
 
 def _chunk_grid_nd(ext: Sequence[int], width: int,
@@ -296,16 +313,18 @@ def _interior_chunks_nd(u: jax.Array,
     ks, wts = _chunk_grid_nd(ext, w, subdomains, weights)
     boxes = interior_boxes(ext, w, ks, wts)  # row-major over the ks grid
     outs = []
-    for b in boxes:
-        src = u
-        for lvl, d in enumerate(dims):
-            src = _sl(src, d, b.start[lvl] - w, b.stop[lvl] + w)
-        outs.append(stencil_fn(src))
-    for lvl in range(len(ks) - 1, -1, -1):  # row-major -> nested concat
-        k = ks[lvl]
-        outs = [outs[i] if k == 1
-                else jnp.concatenate(outs[i:i + k], axis=dims[lvl])
-                for i in range(0, len(outs), k)]
+    with jax.named_scope(INTERIOR):
+        for b in boxes:
+            src = u
+            for lvl, d in enumerate(dims):
+                src = _sl(src, d, b.start[lvl] - w, b.stop[lvl] + w)
+            outs.append(stencil_fn(src))
+        with jax.named_scope(ASSEMBLE):
+            for lvl in range(len(ks) - 1, -1, -1):  # row-major -> nested concat
+                k = ks[lvl]
+                outs = [outs[i] if k == 1
+                        else jnp.concatenate(outs[i:i + k], axis=dims[lvl])
+                        for i in range(0, len(outs), k)]
     return outs[0]
 
 
@@ -313,9 +332,10 @@ def _assemble_nd(faces, interior: jax.Array,
                  dims: Sequence[int]) -> jax.Array:
     """Wrap the interior chunk grid in the face outputs, innermost dim out."""
     out = interior
-    for k in reversed(range(len(dims))):
-        lo, hi = faces[k]
-        out = jnp.concatenate([lo, out, hi], axis=dims[k])
+    with jax.named_scope(ASSEMBLE):
+        for k in reversed(range(len(dims))):
+            lo, hi = faces[k]
+            out = jnp.concatenate([lo, out, hi], axis=dims[k])
     return out
 
 
@@ -329,7 +349,9 @@ def stencil_with_halo_nd(u: jax.Array, halos,
     dims = tuple(dims)
     subdomains = _norm_subn(subdomains, len(dims))
     if any(u.shape[d] < 4 * width for d in dims):  # degenerate: no interior
-        return stencil_fn(pad_with_halo_nd(u, halos, width, dims))
+        padded = pad_with_halo_nd(u, halos, width, dims)
+        with jax.named_scope(INTERIOR):
+            return stencil_fn(padded)
     faces = _faces_nd(u, halos, stencil_fn, width, dims)
     interior = _interior_chunks_nd(u, stencil_fn, width, dims, subdomains,
                                    weights)
@@ -343,7 +365,9 @@ def stencil_two_phase_nd(u: jax.Array,
     """comm(all axes); barrier; compute(whole block) — paper Code 2."""
     dims = tuple(d for _, d in axes)
     halos = exchange_halo_nd(u, axes, width, periodic)
-    return stencil_fn(pad_with_halo_nd(u, halos, width, dims))
+    padded = pad_with_halo_nd(u, halos, width, dims)
+    with jax.named_scope(INTERIOR):
+        return stencil_fn(padded)
 
 
 def stencil_hdot_nd(u: jax.Array,
@@ -432,18 +456,19 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
         # last `w` cells along dim k (faces of LATER axes never reach the
         # edge region — their dim-k extent is the interior range).
         halos_next = []
-        for k, (a, dk) in enumerate(axes):
-            lo_e, hi_e = faces[k]
-            nk = ext[k]
-            for j in reversed(range(k)):
-                lo_j, hi_j = faces[j]
-                lo_e = jnp.concatenate(
-                    [_sl(lo_j, dk, 0, w), lo_e, _sl(hi_j, dk, 0, w)],
-                    axis=dims[j])
-                hi_e = jnp.concatenate(
-                    [_sl(lo_j, dk, nk - w, nk), hi_e,
-                     _sl(hi_j, dk, nk - w, nk)], axis=dims[j])
-            halos_next.append(exchange_edges(lo_e, hi_e, a, periodic))
+        with jax.named_scope(EXCHANGE):
+            for k, (a, dk) in enumerate(axes):
+                lo_e, hi_e = faces[k]
+                nk = ext[k]
+                for j in reversed(range(k)):
+                    lo_j, hi_j = faces[j]
+                    lo_e = jnp.concatenate(
+                        [_sl(lo_j, dk, 0, w), lo_e, _sl(hi_j, dk, 0, w)],
+                        axis=dims[j])
+                    hi_e = jnp.concatenate(
+                        [_sl(lo_j, dk, nk - w, nk), hi_e,
+                         _sl(hi_j, dk, nk - w, nk)], axis=dims[j])
+                halos_next.append(exchange_edges(lo_e, hi_e, a, periodic))
         return halos_next
 
     def body(carry, _):

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.halo import REDUCE
+
 AxisNames = Union[str, Sequence[str]]
 
 _OPS = {
@@ -40,13 +42,14 @@ def task_reduce(partials: Sequence[jax.Array], op: str = "sum") -> jax.Array:
         # bare asserts vanish under `python -O`; this is a caller bug that
         # must surface loudly on the reduction hot path
         raise ValueError("task_reduce needs at least one partial")
-    while len(items) > 1:
-        nxt = []
-        for i in range(0, len(items) - 1, 2):
-            nxt.append(combine(items[i], items[i + 1]))
-        if len(items) % 2:
-            nxt.append(items[-1])
-        items = nxt
+    with jax.named_scope(REDUCE):
+        while len(items) > 1:
+            nxt = []
+            for i in range(0, len(items) - 1, 2):
+                nxt.append(combine(items[i], items[i + 1]))
+            if len(items) % 2:
+                nxt.append(items[-1])
+            items = nxt
     return items[0]
 
 
@@ -59,7 +62,8 @@ def process_allreduce(x: jax.Array, axes: AxisNames, op: str = "sum") -> jax.Arr
 def hdot_reduce(partials: Sequence[jax.Array], axes: AxisNames,
                 op: str = "sum") -> jax.Array:
     """Full paper pattern: task-level tree reduce -> process-level allreduce."""
-    return process_allreduce(task_reduce(partials, op), axes, op)
+    with jax.named_scope(REDUCE):
+        return process_allreduce(task_reduce(partials, op), axes, op)
 
 
 def hierarchical_allreduce(x: jax.Array, inner_axis: str,

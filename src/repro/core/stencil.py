@@ -24,12 +24,17 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.domain import part_extents
-from repro.core.halo import (_norm_subn, exchange_halo, halo_scan_nd,
-                             multi_dim_stencil, pad_with_halo,
-                             stencil_apply_nd, stencil_with_halo_nd)
+from repro.core.halo import (EXCHANGE, INTERIOR, REDUCE, UPDATE, _norm_subn,
+                             exchange_halo, halo_scan_nd, multi_dim_stencil,
+                             pad_with_halo, stencil_apply_nd,
+                             stencil_with_halo_nd)
 from repro.core.reduction import hdot_reduce, task_reduce
 
 _STR_AXES_WARNED: set = set()
+
+# Host span around a solver entry (cut computation, solver lookup, dispatch),
+# on the profiler's clock: a recompile or host stall there shows by name.
+SOLVE_SPAN = "hdot.solve"
 
 
 def normalize_mesh_axes(mesh_axes, solver: str,
@@ -89,10 +94,11 @@ def _heat2d_residual(axes, subdomains: int):
     """paper-Code-5 residual: task-level subdomain MAX partials -> allreduce
     (`axes` may be one mesh axis name or the (rows, cols) pair)."""
     def residual(u_new, u):
-        diff = jnp.abs(u_new - u)
-        chunks = jnp.array_split(diff, subdomains, axis=0)
-        partials = [jnp.max(c) for c in chunks]
-        return hdot_reduce(partials, axes, op="max")
+        with jax.named_scope(REDUCE):
+            diff = jnp.abs(u_new - u)
+            chunks = jnp.array_split(diff, subdomains, axis=0)
+            partials = [jnp.max(c) for c in chunks]
+            return hdot_reduce(partials, axes, op="max")
     return residual
 
 
@@ -191,11 +197,12 @@ def heat2d_solve(u0: jax.Array, mesh, mesh_axes, iters: int,
     grid by measured cost — the dynamic load-balancing path. It is
     canonicalized to chunk extents BEFORE the solver cache, so re-measuring
     identical costs (or an unchanged cut) never recompiles."""
-    axes = normalize_mesh_axes(mesh_axes, "heat2d_solve", (1, 2))
-    if isinstance(subdomains, list):
-        subdomains = tuple(subdomains)
-    cuts = _heat2d_cuts(u0.shape, mesh, axes, subdomains, chunk_weights)
-    return _heat2d_solver(mesh, axes, iters, mode, subdomains, cuts)(u0)
+    with jax.profiler.TraceAnnotation(SOLVE_SPAN):
+        axes = normalize_mesh_axes(mesh_axes, "heat2d_solve", (1, 2))
+        if isinstance(subdomains, list):
+            subdomains = tuple(subdomains)
+        cuts = _heat2d_cuts(u0.shape, mesh, axes, subdomains, chunk_weights)
+        return _heat2d_solver(mesh, axes, iters, mode, subdomains, cuts)(u0)
 
 
 def heat2d_init(nx: int, ny: int, dtype=jnp.float32) -> jax.Array:
@@ -422,10 +429,13 @@ def _stencil27_matvec(p: jax.Array, axis_name: Optional[str], mode: str,
         return stencil_with_halo_nd(p, [halos], fn, width=1, dims=(2,),
                                     subdomains=(subdomains,))
     if axis_name is None:
-        pads = [(0, 0), (0, 0), (1, 1)]
-        return fn(jnp.pad(p, pads))
+        with jax.named_scope(EXCHANGE):
+            padded = jnp.pad(p, [(0, 0), (0, 0), (1, 1)])
+        with jax.named_scope(INTERIOR):
+            return fn(padded)
     return stencil_apply_nd(p, fn, ((axis_name, 2),), width=1,
-                            periodic=False, mode=mode, subdomains=(4,))
+                            periodic=False, mode=mode,
+                            subdomains=(subdomains,))
 
 
 def _chain_fn27(dims: Tuple[int, ...]):
@@ -453,9 +463,10 @@ def _exchange_chain(p: jax.Array, axes: Tuple[str, ...],
     padded block. The final halo planes thus carry every (multi-)corner
     coupling of the 27-point operator with face ppermutes only: one pair per
     axis, no corner messages. Returns (p_padded, lo_last, hi_last)."""
-    for a, d in zip(axes[:-1], dims[:-1]):
-        p = pad_with_halo(p, a, 1, dim=d)
-    lo, hi = exchange_halo(p, axes[-1], 1, dim=dims[-1], periodic=False)
+    with jax.named_scope(EXCHANGE):
+        for a, d in zip(axes[:-1], dims[:-1]):
+            p = pad_with_halo(p, a, 1, dim=d)
+        lo, hi = exchange_halo(p, axes[-1], 1, dim=dims[-1], periodic=False)
     return p, lo, hi
 
 
@@ -475,20 +486,25 @@ def _stencil27_matvec_chain(p: jax.Array, axes: Tuple[str, ...],
         return stencil_with_halo_nd(p1, [(lo, hi)], fn, width=1,
                                     dims=(dims[-1],),
                                     subdomains=(subdomains,))
-    return fn(jnp.concatenate([lo, p1, hi], axis=dims[-1]))
+    with jax.named_scope(EXCHANGE):
+        padded = jnp.concatenate([lo, p1, hi], axis=dims[-1])
+    with jax.named_scope(INTERIOR):
+        return fn(padded)
 
 
 def _ddot(a: jax.Array, b: jax.Array, axis_name: Optional[str],
           subdomains: int = 4) -> jax.Array:
     """paper Code 11: per-subdomain reduction(+) partials, then allreduce."""
-    prod = (a * b).reshape(-1)
-    chunks = jnp.array_split(prod, subdomains)
-    partials = [jnp.sum(c, dtype=jnp.float64 if a.dtype == jnp.float64 else jnp.float32)
-                for c in chunks]
-    local = task_reduce(partials, "sum")
-    if axis_name is None:
-        return local
-    return lax.psum(local, axis_name)
+    with jax.named_scope(REDUCE):
+        prod = (a * b).reshape(-1)
+        chunks = jnp.array_split(prod, subdomains)
+        partials = [jnp.sum(c, dtype=jnp.float64 if a.dtype == jnp.float64
+                            else jnp.float32)
+                    for c in chunks]
+        local = task_reduce(partials, "sum")
+        if axis_name is None:
+            return local
+        return lax.psum(local, axis_name)
 
 
 @functools.lru_cache(maxsize=128)
@@ -524,12 +540,15 @@ def _hpccg_solver(mesh, mesh_axes, iters: int, mode: str, subdomains: int):
 
         def step(x, r, p, rtrans, halos):
             Ap = matvec(p, halos)
-            alpha = rtrans / _ddot(p, Ap, axis_name, subdomains)
-            x = x + alpha * p          # waxpby tasks
-            r = r - alpha * Ap
+            pAp = _ddot(p, Ap, axis_name, subdomains)
+            with jax.named_scope(UPDATE):
+                alpha = rtrans / pAp
+                x = x + alpha * p          # waxpby tasks
+                r = r - alpha * Ap
             rtrans_new = _ddot(r, r, axis_name, subdomains)
-            beta = rtrans_new / rtrans
-            p = r + beta * p
+            with jax.named_scope(UPDATE):
+                beta = rtrans_new / rtrans
+                p = r + beta * p
             return x, r, p, rtrans_new
 
         if pipelined:
@@ -584,5 +603,6 @@ def hpccg_solve(b: jax.Array, mesh, mesh_axes, iters: int,
     — only the boundary-plane tasks of the next matvec wait on them. The
     jitted solver is cached per (mesh, topology, iters, mode, subdomains) so
     repeated solves (and benchmark timings) pay compile once."""
-    axes = normalize_mesh_axes(mesh_axes, "hpccg_solve", (1, 2, 3))
-    return _hpccg_solver(mesh, axes, iters, mode, subdomains)(b)
+    with jax.profiler.TraceAnnotation(SOLVE_SPAN):
+        axes = normalize_mesh_axes(mesh_axes, "hpccg_solve", (1, 2, 3))
+        return _hpccg_solver(mesh, axes, iters, mode, subdomains)(b)

@@ -1,0 +1,157 @@
+"""The HDOT stage scopes (``hdot.*`` named scopes in core/halo.py,
+core/stencil.py and core/reduction.py): every stage a solver runs is named
+in its compiled program's ``op_name`` metadata, in both schedules, on one
+device and on a 4-device mesh; the solver entry points record a host span;
+and the 1-D matvec honours its ``subdomains``.
+
+The 4-device cases compile in one child process on forced host devices
+(``python tests/test_stage_scopes.py`` prints their stage sets)."""
+from __future__ import annotations
+
+import json
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.core import halo, stencil
+from repro.launch.mesh import GRID_AXES, GRID_AXES_3D, make_grid_mesh, make_mesh
+
+REPO = Path(__file__).resolve().parents[1]
+STAGES = (halo.FACES, halo.INTERIOR, halo.ASSEMBLE, halo.EXCHANGE,
+          halo.REDUCE, halo.UPDATE)
+HDOT = {halo.FACES, halo.INTERIOR, halo.ASSEMBLE, halo.EXCHANGE, halo.REDUCE}
+TWO_PHASE = {halo.INTERIOR, halo.EXCHANGE, halo.REDUCE}
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+# (app, mesh shape, mode) -> the stages its compiled solve names. On one
+# device Heat2D's hdot exchange carries zero halos over size-1 axes and the
+# compiler folds it away; HPCCG's chain still pads p there.
+CASES = {
+    ("heat2d", (1, 1), "hdot"): HDOT - {halo.EXCHANGE},
+    ("heat2d", (1, 1), "two_phase"): TWO_PHASE,
+    ("heat2d", (2, 2), "hdot"): HDOT,
+    ("heat2d", (2, 2), "two_phase"): TWO_PHASE,
+    ("hpccg", (1, 1, 1), "hdot"): HDOT | {halo.UPDATE},
+    ("hpccg", (1, 1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},
+    ("hpccg", (1, 2, 2), "hdot"): HDOT | {halo.UPDATE},
+    ("hpccg", (1, 2, 2), "two_phase"): TWO_PHASE | {halo.UPDATE},
+}
+MULTI = [c for c in CASES if np.prod(c[1]) > 1]
+
+
+def _case_id(case):
+    app, shape, mode = case
+    return f"{app}-{'x'.join(map(str, shape))}-{mode}"
+
+
+def innermost_stages(hlo_text: str) -> set:
+    """The innermost ``hdot.*`` scope of each op name in `hlo_text`."""
+    found = set()
+    for m in _OP_NAME.finditer(hlo_text):
+        scopes = [c for c in m.group(1).split("/") if c.startswith("hdot.")]
+        if scopes:
+            found.add(scopes[-1])
+    return found
+
+
+def compiled_stages(app: str, shape, mode: str) -> set:
+    """Stages named in the compiled solve of `app` on a mesh of `shape`, at
+    a tiny size (each chip holds 32^2 cells, or 8^3)."""
+    mesh = make_grid_mesh(*shape, devices=jax.devices()[:int(np.prod(shape))])
+    if app == "heat2d":
+        fn = stencil._heat2d_solver(mesh, GRID_AXES, 4, mode, 4, None)
+        local, spec = (32, 32), P(*GRID_AXES)
+    else:
+        fn = stencil._hpccg_solver(mesh, GRID_AXES_3D, 3, mode, 4)
+        local, spec = (8, 8, 8), P(*GRID_AXES_3D)
+    arg = jax.ShapeDtypeStruct(tuple(n * m for n, m in zip(local, shape)),
+                               jnp.float32, sharding=NamedSharding(mesh, spec))
+    return innermost_stages(fn.lower(arg).compile().as_text())
+
+
+@pytest.fixture(scope="module")
+def multi_device_stages():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, __file__], capture_output=True,
+                         text=True, timeout=600, env=env, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return {k: set(v)
+            for k, v in json.loads(out.stdout.strip().splitlines()[-1]).items()}
+
+
+@pytest.mark.parametrize("case", list(CASES), ids=_case_id)
+def test_compiled_solve_names_its_stages(case, request):
+    app, shape, mode = case
+    if case in MULTI:
+        found = request.getfixturevalue("multi_device_stages")[_case_id(case)]
+    else:
+        found = compiled_stages(app, shape, mode)
+    assert found == CASES[case]
+    assert found <= set(STAGES)
+
+
+def _host_spans(log_dir: str) -> list:
+    from jax.profiler import ProfileData
+
+    [path] = list(Path(log_dir).rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    return [e.name for p in data.planes if p.name == "/host:CPU"
+            for ln in p.lines for e in ln.events]
+
+
+@pytest.mark.parametrize("app", ["heat2d", "hpccg"])
+def test_solver_entry_records_host_span(app, tmp_path):
+    if app == "heat2d":
+        mesh = make_grid_mesh(1, 1, devices=jax.devices()[:1])
+        run = lambda: stencil.heat2d_solve(jnp.ones((16, 16)), mesh, GRID_AXES, 2)
+    else:
+        mesh = make_grid_mesh(1, 1, 1, devices=jax.devices()[:1])
+        run = lambda: stencil.hpccg_solve(jnp.ones((8, 8, 8)), mesh,
+                                          GRID_AXES_3D, 2)
+    jax.block_until_ready(run())                      # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(run())
+    finally:
+        jax.profiler.stop_trace()
+    assert _host_spans(str(tmp_path)).count(stencil.SOLVE_SPAN) == 1
+
+
+@pytest.mark.parametrize("subdomains", [2, 3])
+def test_matvec_1d_honours_subdomains(subdomains, monkeypatch):
+    """The slab matvec cuts its interior into `subdomains` chunks and keeps
+    the two-phase numerics."""
+    seen = []
+    chunks = halo._interior_chunks_nd
+
+    def spy(u, stencil_fn, width, dims, subs, weights=None):
+        seen.append(subs)
+        return chunks(u, stencil_fn, width, dims, subs, weights)
+
+    monkeypatch.setattr(halo, "_interior_chunks_nd", spy)
+    mesh = make_mesh((1,), ("z",))
+    p = jax.random.normal(jax.random.PRNGKey(0), (6, 6, 16), jnp.float32)
+
+    def matvec(mode):
+        f = jax.shard_map(
+            lambda q: stencil._stencil27_matvec(q, "z", mode,
+                                                subdomains=subdomains),
+            mesh=mesh, in_specs=P(None, None, "z"), out_specs=P(None, None, "z"))
+        return np.asarray(jax.jit(f)(p))
+
+    np.testing.assert_allclose(matvec("hdot"), matvec("two_phase"),
+                               rtol=1e-6, atol=1e-5)
+    assert seen == [(subdomains,)]
+
+
+if __name__ == "__main__":
+    print(json.dumps({_case_id(c): sorted(compiled_stages(*c)) for c in MULTI}))
