@@ -296,29 +296,41 @@ def _chunk_grid_nd(ext: Sequence[int], width: int,
     return ks, wts
 
 
-def _interior_chunks_nd(u: jax.Array,
-                        stencil_fn: Callable[[jax.Array], jax.Array],
-                        width: int, dims: Sequence[int],
-                        subdomains: Tuple[int, ...],
-                        weights=None) -> jax.Array:
-    """Interior cells [w, n-w) per decomposed dim as an N-D grid of
-    independent chunk tasks, cut by `interior_boxes` — the process-level
+def _chunk_tasks_nd(u: jax.Array,
+                    stencil_fn: Callable[[jax.Array], jax.Array],
+                    width: int, dims: Sequence[int],
+                    subdomains: Tuple[int, ...], weights=None):
+    """The interior chunk tasks: cells [w, n-w) per decomposed dim as an N-D
+    grid of independent chunks, cut by `interior_boxes` — the process-level
     partition scheme reused at task level. A chunk reads only its subdomain
-    plus `width` ghosts, so chunks are disjoint work the latency-hiding
-    scheduler interleaves with every axis's ppermutes. `weights` (per-dim
-    explicit chunk extents) makes the grid UNEVEN — the measured-cost re-cut —
-    without touching the face partition."""
+    plus `width` ghosts. Returns the chunk outputs and their boxes, row-major
+    over the chunk grid, and the grid's per-dim chunk counts."""
     w = width
     ext = [u.shape[d] for d in dims]
     ks, wts = _chunk_grid_nd(ext, w, subdomains, weights)
     boxes = interior_boxes(ext, w, ks, wts)  # row-major over the ks grid
     outs = []
+    for b in boxes:
+        src = u
+        for lvl, d in enumerate(dims):
+            src = _sl(src, d, b.start[lvl] - w, b.stop[lvl] + w)
+        outs.append(stencil_fn(src))
+    return outs, boxes, ks
+
+
+def _interior_chunks_nd(u: jax.Array,
+                        stencil_fn: Callable[[jax.Array], jax.Array],
+                        width: int, dims: Sequence[int],
+                        subdomains: Tuple[int, ...],
+                        weights=None) -> jax.Array:
+    """The interior as one array: the chunk tasks of :func:`_chunk_tasks_nd`
+    concatenated back together. Chunks are disjoint work the latency-hiding
+    scheduler interleaves with every axis's ppermutes. `weights` (per-dim
+    explicit chunk extents) makes the grid UNEVEN — the measured-cost re-cut —
+    without touching the face partition."""
     with jax.named_scope(INTERIOR):
-        for b in boxes:
-            src = u
-            for lvl, d in enumerate(dims):
-                src = _sl(src, d, b.start[lvl] - w, b.stop[lvl] + w)
-            outs.append(stencil_fn(src))
+        outs, _, ks = _chunk_tasks_nd(u, stencil_fn, width, dims, subdomains,
+                                      weights)
         with jax.named_scope(ASSEMBLE):
             for lvl in range(len(ks) - 1, -1, -1):  # row-major -> nested concat
                 k = ks[lvl]
@@ -402,48 +414,80 @@ def stencil_apply_nd(u: jax.Array,
 def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
                  axes: Axes, width: int, steps: int,
                  periodic: bool = False, mode: str = "hdot", subdomains=2,
-                 step_out_fn: Optional[Callable[[jax.Array, jax.Array],
-                                                jax.Array]] = None,
-                 unroll: int = 1, peel: bool = True, weights=None
-                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
+                 partial_fn: Optional[Callable[[jax.Array, jax.Array],
+                                               jax.Array]] = None,
+                 unroll: int = 1, peel: bool = True,
+                 weights=None) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Double-buffered multi-step stencil driver on an N-D process mesh.
 
-    In hdot mode the scan carry is (block, per-axis halos): the halos for
-    step k arrive with the carry, so the body can (1) finish step k's 2·N
-    boundary faces — the only halo consumers; (2) IMMEDIATELY launch EVERY
-    axis's ppermute pair for step k+1 (the new block's axis-k edges are
-    stitched from the face outputs alone, corner-free); (3) only then chew
-    through step k's interior chunk grid. All N exchanges are therefore
-    always in flight behind the interior compute; the only exposed latency
-    is the pipeline-fill exchange before the scan.
+    In hdot mode the halos for step k arrive with the loop carry, so a step
+    can (1) finish its 2·N boundary faces — the only halo consumers;
+    (2) IMMEDIATELY launch EVERY axis's ppermute pair for step k+1 (the new
+    block's axis-k edges are stitched from the face outputs alone,
+    corner-free); (3) only then chew through its interior chunk grid. All N
+    exchanges are therefore always in flight behind the interior compute;
+    the only exposed latency is the pipeline-fill exchange before the loop.
 
-    The final step is PEELED out of the scan (pipeline drain): the in-body
-    exchange would feed a step that never runs, so the scan covers steps-1
-    trips and the last step consumes its carried halos without launching new
-    ppermutes — N dead exchange pairs per solve saved (``peel=False`` keeps
-    the old drain-in-scan lowering; regression tests count the ppermutes).
+    Task-owned output: the loop carries two blocks and a step reads one and
+    writes the other — every face and chunk task writes its cells straight
+    into the spare block (`dynamic_update_slice` at its box), so no
+    concatenate assembles the block. The loop runs two steps a trip (A -> B,
+    B -> A), so each block keeps its slot in the carry: a loop that swapped
+    them would make the compiler copy a block every trip. The spare is
+    allocated once a solve; an odd step count takes one step after the
+    loop.
 
-    `step_out_fn(u_new, u_old)` optionally produces a per-step output (e.g. a
-    residual); its stacked results are returned as the second element (None
-    when not provided). Numerics are identical to `steps` iterated calls of
-    :func:`stencil_apply_nd` — asserted in tests. `unroll` is forwarded to
-    lax.scan (the HLO-inspection tests unroll fully so every exchange is a
-    countable op definition). `weights` (per-dim explicit chunk extents from
-    :func:`repro.core.domain.interior_cuts`) cuts the interior chunk grid
-    unevenly — the face partition and the ppermute schedule are unchanged, so
-    a measured-cost re-cut never alters the communication shape.
+    The final step is PEELED out of the loop (pipeline drain): the in-loop
+    exchange would feed a step that never runs, so the loop covers steps-1
+    steps and the last step consumes its carried halos without launching
+    new ppermutes — N dead exchange pairs per solve saved. ``peel=False``
+    keeps the dead exchange for the regression tests that count ppermutes:
+    with an even step count the last step runs inside the loop and launches
+    its exchange there; with an odd count the last step follows the loop
+    and the compiler drops its unused exchange from the optimized program.
+
+    `partial_fn(new, old)` optionally gives a per-step output the paper's
+    Code 5 way: each task maps its own new cells and the old cells they
+    replace to a partial (e.g. ``max |new - old|`` for a residual), taken in
+    the task's own stage scope, and the step's partials are max-combined by
+    :func:`repro.core.reduction.hdot_reduce` over the tasks and the
+    decomposition's mesh axes. A partial reads only the cells its task's
+    stencil reads, so it adds no pass over the blocks;
+    an `f(new, old)` over both whole blocks after the step would, and would
+    make the compiler copy the old block, which the next step overwrites.
+    The stacked per-step results are returned as the second element (None
+    without `partial_fn`). Numerics are identical to `steps` iterated calls
+    of :func:`stencil_apply_nd` — asserted in tests. `unroll` is forwarded
+    to lax.scan over the loop's trips (the HLO-inspection tests unroll fully
+    so every exchange is a countable op definition). `weights` (per-dim
+    explicit chunk extents from :func:`repro.core.domain.interior_cuts`)
+    cuts the interior chunk grid unevenly — the face partition and the
+    ppermute schedule are unchanged, so a measured-cost re-cut never alters
+    the communication shape.
     """
     axes = tuple((a, d) for a, d in axes)
     dims = tuple(d for _, d in axes)
+    names = tuple(a for a, _ in axes)
     w = width
     ext = tuple(u.shape[d] for d in dims)
+
+    def reduce_partials(parts):
+        # core.reduction imports this module's stage names: import late
+        from repro.core.reduction import hdot_reduce
+        return hdot_reduce(parts, names, "max")
+
     if mode != "hdot" or any(n < 4 * w for n in ext) or steps < 1:
         # two-phase baseline (or degenerate block / empty scan, which keeps
-        # the length-0 stacked-outs contract): plain comm->compute scan
+        # the length-0 stacked-outs contract): plain comm->compute scan, the
+        # whole block one task
         def body(u, _):
             u_new = stencil_apply_nd(u, stencil_fn, axes, w, periodic,
                                      mode, subdomains, weights)
-            return u_new, step_out_fn(u_new, u) if step_out_fn else None
+            if partial_fn is None:
+                return u_new, None
+            with jax.named_scope(REDUCE):
+                part = partial_fn(u_new, u)
+            return u_new, reduce_partials([part])
         return lax.scan(body, u, None, length=steps, unroll=unroll)
 
     subdomains = _norm_subn(subdomains, len(dims))
@@ -471,31 +515,70 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
                 halos_next.append(exchange_edges(lo_e, hi_e, a, periodic))
         return halos_next
 
-    def body(carry, _):
-        u, halos = carry
-        faces = _faces_nd(u, halos, stencil_fn, w, dims)
-        halos_next = exchange_from_faces(faces)
-        interior = _interior_chunks_nd(u, stencil_fn, w, dims, subdomains,
-                                       weights)
-        u_new = _assemble_nd(faces, interior, dims)
-        out = step_out_fn(u_new, u) if step_out_fn else None
-        return (u_new, halos_next), out
+    def face_start(k, side):
+        # face (k, side)'s first cell along the decomposed dims (the onion)
+        return tuple(w if j < k else
+                     (ext[k] - w if j == k and side == "hi" else 0)
+                     for j in range(len(dims)))
 
-    halos0 = exchange_halo_nd(u, axes, w, periodic)  # pipeline fill
-    if not peel:
-        (u, _), outs = lax.scan(body, (u, halos0), None, length=steps,
-                                unroll=unroll)
-        return u, outs
-    (u, halos), outs = lax.scan(body, (u, halos0), None, length=steps - 1,
-                                unroll=unroll)
-    # Peeled drain: the last step consumes its halos, launches nothing.
-    u_new = stencil_with_halo_nd(u, halos, stencil_fn, w, dims, subdomains,
-                                 weights)
-    if step_out_fn is not None:
-        outs = jax.tree.map(
-            lambda s, o: jnp.concatenate([s, o[None]], axis=0), outs,
-            step_out_fn(u_new, u))
-    return u_new, outs
+    def step(src, dst, halos, exchange):
+        """One step of `src`, each task writing its cells into `dst`;
+        returns (dst, the next step's halos or None, the reduced partials
+        or None)."""
+        faces = _faces_nd(src, halos, stencil_fn, w, dims)
+        halos_next = exchange_from_faces(faces) if exchange else None
+        tasks = [(FACES, out, face_start(k, side))
+                 for k, pair in enumerate(faces)
+                 for side, out in zip(("lo", "hi"), pair)]
+        with jax.named_scope(INTERIOR):
+            outs, boxes, _ = _chunk_tasks_nd(src, stencil_fn, w, dims,
+                                             subdomains, weights)
+        tasks += [(INTERIOR, out, b.start) for out, b in zip(outs, boxes)]
+        parts = []
+        for scope, out, start in tasks:
+            if out.size == 0:  # an explicit cut may hold empty chunks
+                continue
+            at = [0] * src.ndim
+            for d, s in zip(dims, start):
+                at[d] = s
+            with jax.named_scope(ASSEMBLE):
+                dst = lax.dynamic_update_slice(dst, out, at)
+            if partial_fn is not None:
+                # the task's own scope: the compiler fuses the partial into
+                # the task's stencil, whose time must read as that stage's
+                with jax.named_scope(scope):
+                    old = lax.slice(src, at, [a + n for a, n in
+                                              zip(at, out.shape)])
+                    parts.append(partial_fn(out, old))
+        return dst, halos_next, (reduce_partials(parts)
+                                 if partial_fn is not None else None)
+
+    def trip(carry, _):
+        a, b, halos = carry
+        b, halos, r0 = step(a, b, halos, True)
+        a, halos, r1 = step(b, a, halos, True)
+        return (a, b, halos), (None if partial_fn is None
+                               else jnp.stack([r0, r1]))
+
+    halos = exchange_halo_nd(u, axes, w, periodic)  # pipeline fill
+    a, b = u, jnp.zeros_like(u)                    # b: the spare block
+    trips, odd = divmod(steps - 1 if peel else steps, 2)
+    outs = []                                      # per-step results, stacked
+    if trips:
+        (a, b, halos), o = lax.scan(trip, (a, b, halos), None, length=trips,
+                                    unroll=unroll)
+        outs.append(None if o is None else o.reshape((-1,) + o.shape[2:]))
+    if odd:
+        b, halos, r = step(a, b, halos, True)
+        a, b = b, a
+        outs.append(None if r is None else r[None])
+    if peel:
+        # Peeled drain: the last step consumes its halos, launches nothing.
+        a, _, r = step(a, b, halos, False)
+        outs.append(None if r is None else r[None])
+    if partial_fn is None:
+        return a, None
+    return a, jnp.concatenate(outs)
 
 
 # --------------------------------------------------------------------------
@@ -550,14 +633,14 @@ def halo_scan(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
               axis_name: str, width: int, dim: int, steps: int,
               periodic: bool = False, mode: str = "hdot",
               subdomains: int = 4,
-              step_out_fn: Optional[Callable[[jax.Array, jax.Array], jax.Array]]
+              partial_fn: Optional[Callable[[jax.Array, jax.Array], jax.Array]]
               = None, unroll: int = 1,
               peel: bool = True) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Deprecated alias: double-buffered multi-step driver on one mesh axis
     (see :func:`halo_scan_nd` for the schedule)."""
     _warn_deprecated("halo_scan", "halo_scan_nd")
     return halo_scan_nd(u, stencil_fn, ((axis_name, dim),), width, steps,
-                        periodic, mode, (subdomains,), step_out_fn, unroll,
+                        periodic, mode, (subdomains,), partial_fn, unroll,
                         peel)
 
 
@@ -644,8 +727,8 @@ def halo_scan_2d(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
                  axis_names: Tuple[str, str], width: int,
                  dims: Tuple[int, int], steps: int, periodic: bool = False,
                  mode: str = "hdot", subdomains=(2, 2),
-                 step_out_fn: Optional[Callable[[jax.Array, jax.Array],
-                                                jax.Array]] = None,
+                 partial_fn: Optional[Callable[[jax.Array, jax.Array],
+                                               jax.Array]] = None,
                  unroll: int = 1, peel: bool = True
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Deprecated alias: double-buffered multi-step driver on a (rows x cols)
@@ -654,7 +737,7 @@ def halo_scan_2d(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
     _warn_deprecated("halo_scan_2d", "halo_scan_nd")
     return halo_scan_nd(u, stencil_fn, tuple(zip(axis_names, dims)), width,
                         steps, periodic, mode, _norm_sub2(subdomains),
-                        step_out_fn, unroll, peel)
+                        partial_fn, unroll, peel)
 
 
 def multi_dim_stencil(u: jax.Array,

@@ -28,7 +28,7 @@ from repro.core.halo import (EXCHANGE, INTERIOR, REDUCE, UPDATE, _norm_subn,
                              exchange_halo, halo_scan_nd, multi_dim_stencil,
                              pad_with_halo, stencil_apply_nd,
                              stencil_with_halo_nd)
-from repro.core.reduction import hdot_reduce, task_reduce
+from repro.core.reduction import task_reduce
 
 _STR_AXES_WARNED: set = set()
 
@@ -90,16 +90,10 @@ def _jacobi_stencil_2d(padded: jax.Array) -> jax.Array:
                    + padded[1:-1, :-2] + padded[1:-1, 2:])
 
 
-def _heat2d_residual(axes, subdomains: int):
-    """paper-Code-5 residual: task-level subdomain MAX partials -> allreduce
-    (`axes` may be one mesh axis name or the (rows, cols) pair)."""
-    def residual(u_new, u):
-        with jax.named_scope(REDUCE):
-            diff = jnp.abs(u_new - u)
-            chunks = jnp.array_split(diff, subdomains, axis=0)
-            partials = [jnp.max(c) for c in chunks]
-            return hdot_reduce(partials, axes, op="max")
-    return residual
+def _abs_change(new: jax.Array, old: jax.Array) -> jax.Array:
+    """One task's residual partial (paper Code 5): its largest |new - old|.
+    halo_scan_nd max-reduces the partials over the tasks and the mesh."""
+    return jnp.max(jnp.abs(new - old))
 
 
 @functools.lru_cache(maxsize=128)
@@ -114,16 +108,13 @@ def _heat2d_solver(mesh, axes, iters: int, mode: str, subdomains, cuts=None):
     axes = normalize_mesh_axes(axes, "heat2d_solve", (1, 2))
     subs = _norm_subn(subdomains, len(axes))
     hs_axes = tuple((a, d) for d, a in enumerate(axes))
-    n_chunks = 1
-    for s in subs:
-        n_chunks *= s
     stencil_fn = _jacobi_stencil_2d if len(axes) == 2 else _jacobi_stencil
 
     def local(u):
         return halo_scan_nd(
             u, stencil_fn, hs_axes, width=1, steps=iters, periodic=False,
-            mode=mode, subdomains=subs,
-            step_out_fn=_heat2d_residual(axes, n_chunks), weights=cuts)
+            mode=mode, subdomains=subs, partial_fn=_abs_change,
+            weights=cuts)
 
     spec = P(*axes) if len(axes) == 2 else P(axes[0], None)
     f = jax.shard_map(local, mesh=mesh, in_specs=spec,

@@ -2,8 +2,12 @@
 is reserved for the dry-run and the benchmark subprocess workers."""
 from __future__ import annotations
 
+import json
 import os
 import re
+import subprocess
+import sys
+from pathlib import Path
 
 # Guard: the in-process suite must see the default single CPU device. CI
 # exports XLA_FLAGS=--xla_force_host_platform_device_count=8 at the job level
@@ -40,3 +44,18 @@ def reduced(arch_id: str):
     from repro.config.registry import get_arch
 
     return get_arch(arch_id).reduced()
+
+
+@pytest.fixture(scope="module")
+def child_results(request):
+    """The JSON line the requesting test file prints last when it runs as a
+    script on 8 forced host devices: the file's cases that need a real
+    multi-device mesh, all computed in one child process."""
+    path = Path(request.module.__file__)
+    repo = path.parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(repo / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    out = subprocess.run([sys.executable, str(path)], capture_output=True,
+                         text=True, timeout=600, env=env, cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])

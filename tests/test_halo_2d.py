@@ -6,6 +6,7 @@ exchange must still get right for star stencils."""
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +15,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.domain import interior_boxes
-from repro.core.halo import (halo_scan_2d, pad_with_halo_2d, stencil_apply_2d,
-                             stencil_with_halo_2d)
+from repro.core.halo import (halo_scan_2d, halo_scan_nd, pad_with_halo_2d,
+                             stencil_apply_2d, stencil_with_halo_2d)
 
 
 @pytest.fixture(scope="module")
@@ -27,14 +28,18 @@ def grid_mesh():
 
 def _star_fn(width: int):
     """Separable star stencil of `width` (reads the full cross, no corners).
-    Input padded by `width` on both dims; returns the un-padded update."""
+    Input padded by `width` on both dims; returns the un-padded update. The
+    sum is scaled by a power of two, so the product is exact and a compiler
+    that fuses a multiply into the next add (an FMA) rounds no differently:
+    schedules can then be compared bit for bit."""
+    scale = 0.5 ** (2 * (2 * width + 1)).bit_length()
     def fn(p):
         n0, n1 = p.shape[0] - 2 * width, p.shape[1] - 2 * width
         acc = 0.0
         for d in range(-width, width + 1):
             acc = (acc + p[width + d:width + d + n0, width:width + n1]
                    + p[width:width + n0, width + d:width + d + n1])
-        return acc / (2 * (2 * width + 1))
+        return acc * scale
     return fn
 
 
@@ -71,31 +76,86 @@ def test_stencil_hdot_2d_matches_two_phase(grid_mesh, subdomains, periodic):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("mode", ["hdot", "two_phase"])
-@pytest.mark.parametrize("width,shape", [(1, (17, 13)), (1, (16, 20)),
-                                         (2, (21, 18))])
-def test_halo_scan_2d_equals_iterated_apply(grid_mesh, mode, width, shape):
-    """halo_scan_2d(steps=k) == k iterated 2-D applies, odd AND even interior
-    sizes, both schedules."""
-    steps = 4
-    u = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
-    fn = _star_fn(width)
+def scan_matches_iterated(mesh_shape, mode, width, block, steps, peel,
+                          weights):
+    """halo_scan_2d (halo_scan_nd with an explicit cut) against `steps`
+    iterated two-phase applies on a (rows, cols) mesh of `mesh_shape`, each
+    chip holding `block`: are the grids, and the per-step max |new - old|,
+    equal bit for bit?"""
+    from repro.launch.mesh import make_grid_mesh
 
-    got, _ = jax.jit(jax.shard_map(
-        lambda x: halo_scan_2d(x, fn, ("rows", "cols"), width, (0, 1), steps,
-                               periodic=True, mode=mode, subdomains=(3, 2)),
-        mesh=grid_mesh, in_specs=(P("rows", "cols"),),
-        out_specs=(P("rows", "cols"), P())))(u)
+    mesh = make_grid_mesh(*mesh_shape,
+                          devices=jax.devices()[:int(np.prod(mesh_shape))])
+    u = jax.random.normal(jax.random.PRNGKey(1),
+                          tuple(b * m for b, m in zip(block, mesh_shape)),
+                          jnp.float32)
+    fn = _star_fn(width)
+    axes = ("rows", "cols")
+
+    def change(new, old):
+        return jnp.max(jnp.abs(new - old))
+
+    def scan(x):
+        if weights is None:
+            return halo_scan_2d(x, fn, axes, width, (0, 1), steps,
+                                periodic=True, mode=mode, subdomains=(3, 2),
+                                partial_fn=change, peel=peel)
+        return halo_scan_nd(x, fn, tuple(zip(axes, (0, 1))), width, steps,
+                            periodic=True, mode=mode, partial_fn=change,
+                            peel=peel, weights=weights)
 
     def iterate(x):
+        hist = []
         for _ in range(steps):
-            x = stencil_apply_2d(x, fn, ("rows", "cols"), width, (0, 1),
-                                 True, "two_phase")
-        return x
+            new = stencil_apply_2d(x, fn, axes, width, (0, 1), True,
+                                   "two_phase")
+            hist.append(jax.lax.pmax(change(new, x), axes))
+            x = new
+        return x, jnp.stack(hist)
 
-    want = _shmap(iterate, grid_mesh)(u)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    specs = dict(mesh=mesh, in_specs=(P(*axes),), out_specs=(P(*axes), P()))
+    got = jax.jit(jax.shard_map(scan, **specs))(u)
+    want = jax.jit(jax.shard_map(iterate, **specs))(u)
+    return [bool(np.array_equal(np.asarray(g), np.asarray(w)))
+            for g, w in zip(got, want)]
+
+
+# (mesh, mode, width, block, steps, peel, weights). The first six keep the
+# ids they had as a product of (width, block) and mode; the rest cover odd
+# and even step counts, the unpeeled drain, an uneven interior cut and a
+# real 2x2 exchange.
+SCAN_CASES = [
+    pytest.param((1, 1), mode, width, block, 4, True, None,
+                 id=f"{width}-shape{i}-{mode}")
+    for i, (width, block) in enumerate([(1, (17, 13)), (1, (16, 20)),
+                                        (2, (21, 18))])
+    for mode in ("hdot", "two_phase")]
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (17, 13), steps, peel, None,
+                 id=f"{mesh[0]}x{mesh[1]}-steps{steps}-"
+                    f"{'peeled' if peel else 'unpeeled'}")
+    for mesh in ((1, 1), (2, 2)) for steps in (1, 2, 3, 5)
+    for peel in (True, False)]
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (17, 13), 3, True, ((2, 9, 4), (8, 3)),
+                 id=f"{mesh[0]}x{mesh[1]}-uneven-cut")
+    for mesh in ((1, 1), (2, 2))]
+
+
+@pytest.mark.parametrize("mesh_shape,mode,width,block,steps,peel,weights",
+                         SCAN_CASES)
+def test_halo_scan_2d_equals_iterated_apply(mesh_shape, mode, width, block,
+                                            steps, peel, weights, request):
+    """halo_scan_2d(steps=k) == k iterated two-phase 2-D applies, bit for
+    bit, grid and per-step residual: odd AND even interior sizes and step
+    counts, both schedules, peeled or not, uniform or uneven interior cut;
+    the 2x2 cases run in one child process on forced host devices."""
+    if np.prod(mesh_shape) > 1:
+        found = request.getfixturevalue("child_results")[request.node.callspec.id]
+    else:
+        found = scan_matches_iterated(mesh_shape, mode, width, block, steps,
+                                      peel, weights)
+    assert found == [True, True]
 
 
 def test_stencil_with_halo_2d_uses_given_halos(grid_mesh):
@@ -186,3 +246,8 @@ def test_heat2d_kernel_halo_ring_pallas_vs_ref():
                                  halo=halo)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+if __name__ == "__main__":
+    print(json.dumps({c.id: scan_matches_iterated(*c.values)
+                      for c in SCAN_CASES if np.prod(c.values[0]) > 1}))

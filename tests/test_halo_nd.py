@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,10 @@ def grid_mesh3():
 def _star3_fn(width: int):
     """Separable 3-D star stencil of `width` (reads the full 3-axis cross,
     never a corner). Input padded by `width` on all three dims; returns the
-    un-padded update."""
+    un-padded update. The sum is scaled by a power of two, so the product
+    is exact and an FMA rounds no differently: schedules can then be
+    compared bit for bit."""
+    scale = 0.5 ** (3 * (2 * width + 1)).bit_length()
     def fn(p):
         w = width
         n0, n1, n2 = (s - 2 * w for s in p.shape)
@@ -44,7 +48,7 @@ def _star3_fn(width: int):
                    + p[w + d:w + d + n0, w:w + n1, w:w + n2]
                    + p[w:w + n0, w + d:w + d + n1, w:w + n2]
                    + p[w:w + n0, w:w + n1, w + d:w + d + n2])
-        return acc / (3 * (2 * w + 1))
+        return acc * scale
     return fn
 
 
@@ -84,30 +88,81 @@ def test_stencil_hdot_nd_matches_two_phase(grid_mesh3, subdomains, periodic):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("mode", ["hdot", "two_phase"])
-@pytest.mark.parametrize("width,shape", [(1, (11, 9, 13)), (1, (12, 10, 8)),
-                                         (2, (13, 11, 10))])
-def test_halo_scan_nd_equals_iterated_apply(grid_mesh3, mode, width, shape):
-    """halo_scan_nd(steps=k) == k iterated 3-D applies, odd AND even
-    extents, both schedules."""
-    steps = 3
-    u = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+def scan_matches_iterated(mesh_shape, mode, width, block, steps, peel,
+                          weights):
+    """halo_scan_nd against `steps` iterated two-phase 3-D applies on a
+    (planes, rows, cols) mesh of `mesh_shape`, each chip holding `block`:
+    are the grids, and the per-step max |new - old|, equal bit for bit?"""
+    from repro.launch.mesh import make_grid_mesh
+
+    mesh = make_grid_mesh(*mesh_shape,
+                          devices=jax.devices()[:int(np.prod(mesh_shape))])
+    u = jax.random.normal(jax.random.PRNGKey(1),
+                          tuple(b * m for b, m in zip(block, mesh_shape)),
+                          jnp.float32)
     fn = _star3_fn(width)
 
-    got, _ = jax.jit(jax.shard_map(
-        lambda x: halo_scan_nd(x, fn, DECOMP3, width, steps, periodic=True,
-                               mode=mode, subdomains=(2, 2, 1)),
-        mesh=grid_mesh3, in_specs=(P(*AXES3),),
-        out_specs=(P(*AXES3), P())))(u)
+    def change(new, old):
+        return jnp.max(jnp.abs(new - old))
+
+    def scan(x):
+        return halo_scan_nd(x, fn, DECOMP3, width, steps, periodic=True,
+                            mode=mode, subdomains=(2, 2, 1),
+                            partial_fn=change, peel=peel, weights=weights)
 
     def iterate(x):
+        hist = []
         for _ in range(steps):
-            x = stencil_apply_nd(x, fn, DECOMP3, width, True, "two_phase")
-        return x
+            new = stencil_apply_nd(x, fn, DECOMP3, width, True, "two_phase")
+            hist.append(jax.lax.pmax(change(new, x), AXES3))
+            x = new
+        return x, jnp.stack(hist)
 
-    want = _shmap(iterate, grid_mesh3)(u)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    specs = dict(mesh=mesh, in_specs=(P(*AXES3),),
+                 out_specs=(P(*AXES3), P()))
+    got = jax.jit(jax.shard_map(scan, **specs))(u)
+    want = jax.jit(jax.shard_map(iterate, **specs))(u)
+    return [bool(np.array_equal(np.asarray(g), np.asarray(w)))
+            for g, w in zip(got, want)]
+
+
+# (mesh, mode, width, block, steps, peel, weights). The first six keep the
+# ids they had as a product of (width, block) and mode; the rest cover odd
+# and even step counts, the unpeeled drain, an uneven interior cut (with an
+# empty chunk) and a real 2x2x2 exchange.
+SCAN_CASES = [
+    pytest.param((1, 1, 1), mode, width, block, 3, True, None,
+                 id=f"{width}-shape{i}-{mode}")
+    for i, (width, block) in enumerate([(1, (11, 9, 13)), (1, (12, 10, 8)),
+                                        (2, (13, 11, 10))])
+    for mode in ("hdot", "two_phase")]
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (11, 9, 13), steps, peel, None,
+                 id=f"{'x'.join(map(str, mesh))}-steps{steps}-"
+                    f"{'peeled' if peel else 'unpeeled'}")
+    for mesh in ((1, 1, 1), (2, 2, 2)) for steps in (1, 2, 3, 5)
+    for peel in (True, False)]
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (11, 9, 13), 3, True,
+                 ((6, 3), None, (0, 4, 7)),
+                 id=f"{'x'.join(map(str, mesh))}-uneven-cut")
+    for mesh in ((1, 1, 1), (2, 2, 2))]
+
+
+@pytest.mark.parametrize("mesh_shape,mode,width,block,steps,peel,weights",
+                         SCAN_CASES)
+def test_halo_scan_nd_equals_iterated_apply(mesh_shape, mode, width, block,
+                                            steps, peel, weights, request):
+    """halo_scan_nd(steps=k) == k iterated two-phase 3-D applies, bit for
+    bit, grid and per-step residual: odd AND even extents and step counts,
+    both schedules, peeled or not, uniform or uneven interior cut; the
+    2x2x2 cases run in one child process on forced host devices."""
+    if np.prod(mesh_shape) > 1:
+        found = request.getfixturevalue("child_results")[request.node.callspec.id]
+    else:
+        found = scan_matches_iterated(mesh_shape, mode, width, block, steps,
+                                      peel, weights)
+    assert found == [True, True]
 
 
 def test_stencil_with_halo_nd_uses_given_halos():
@@ -180,3 +235,8 @@ def test_hpccg_3d_mesh_matches_slab(grid_mesh3):
         _, h = hpccg_solve(b, grid_mesh3, AXES3, 15, mode=mode)
         np.testing.assert_allclose(np.asarray(h), np.asarray(h_want),
                                    rtol=1e-4)
+
+
+if __name__ == "__main__":
+    print(json.dumps({c.id: scan_matches_iterated(*c.values)
+                      for c in SCAN_CASES if np.prod(c.values[0]) > 1}))

@@ -76,12 +76,12 @@ def test_halo_scan_equals_iterated_apply(data_mesh, mode, width, fn):
 
 
 def test_halo_scan_step_outputs(data_mesh):
-    """step_out_fn results are stacked per step, in order."""
+    """Task partials are reduced per step and stacked per step, in order."""
     u = jnp.ones((16, 3), jnp.float32)
     _, outs = jax.jit(jax.shard_map(
         lambda x: halo_scan(x, _avg3, "data", 1, 0, 4, periodic=True,
-                            step_out_fn=lambda new, old: jax.lax.pmax(
-                                jnp.max(jnp.abs(new - old)), "data")),
+                            partial_fn=lambda new, old: jnp.max(
+                                jnp.abs(new - old))),
         mesh=data_mesh, in_specs=(P("data"),),
         out_specs=(P("data"), P())))(u)
     assert outs.shape == (4,)
@@ -129,8 +129,7 @@ def test_halo_scan_peel_numerics_identical(data_mesh):
         return jax.jit(jax.shard_map(
             lambda x: halo_scan(x, _avg3, "data", 1, 0, 5, periodic=True,
                                 peel=peel,
-                                step_out_fn=lambda new, old: jax.lax.pmax(
-                                    jnp.max(new), "data")),
+                                partial_fn=lambda new, old: jnp.max(new)),
             mesh=data_mesh, in_specs=(P("data"),),
             out_specs=(P("data"), P())))(u)
 

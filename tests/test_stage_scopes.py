@@ -9,10 +9,7 @@ The 4-device cases compile in one child process on forced host devices
 from __future__ import annotations
 
 import json
-import os
 import re
-import subprocess
-import sys
 from pathlib import Path
 
 import jax
@@ -24,7 +21,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import halo, stencil
 from repro.launch.mesh import GRID_AXES, GRID_AXES_3D, make_grid_mesh, make_mesh
 
-REPO = Path(__file__).resolve().parents[1]
 STAGES = (halo.FACES, halo.INTERIOR, halo.ASSEMBLE, halo.EXCHANGE,
           halo.REDUCE, halo.UPDATE)
 HDOT = {halo.FACES, halo.INTERIOR, halo.ASSEMBLE, halo.EXCHANGE, halo.REDUCE}
@@ -77,22 +73,11 @@ def compiled_stages(app: str, shape, mode: str) -> set:
     return innermost_stages(fn.lower(arg).compile().as_text())
 
 
-@pytest.fixture(scope="module")
-def multi_device_stages():
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO / "src"),
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run([sys.executable, __file__], capture_output=True,
-                         text=True, timeout=600, env=env, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-3000:]
-    return {k: set(v)
-            for k, v in json.loads(out.stdout.strip().splitlines()[-1]).items()}
-
-
 @pytest.mark.parametrize("case", list(CASES), ids=_case_id)
 def test_compiled_solve_names_its_stages(case, request):
     app, shape, mode = case
     if case in MULTI:
-        found = request.getfixturevalue("multi_device_stages")[_case_id(case)]
+        found = set(request.getfixturevalue("child_results")[_case_id(case)])
     else:
         found = compiled_stages(app, shape, mode)
     assert found == CASES[case]

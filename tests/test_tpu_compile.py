@@ -1,7 +1,9 @@
-"""The main path's Pallas kernels and one decode step compiled for a
-described (not attached) TPU v5e chip at real widths: what the TPU compiler
-refuses (block shapes off the (8, 128) tiling, too much VMEM, a program that
-does not fit HBM) fails here without a chip. Nothing runs.
+"""The main path's Pallas kernels, one decode step and the Heat2D solve
+compiled for a described (not attached) TPU v5e chip at real widths: what
+the TPU compiler refuses (block shapes off the (8, 128) tiling, too much
+VMEM, a program that does not fit HBM) or the HBM passes it adds (a block
+copied or concatenated inside the sweep loop) fail here without a chip.
+Nothing runs.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and under pytest-xdist every
@@ -11,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,3 +133,61 @@ def test_internlm2_decode_step_compiles_full_width(one_chip):
     mem = compiled.memory_analysis()
     # the caches are donated: updated in place, not copied out
     assert mem.alias_size_in_bytes > 0
+
+
+_FUSED = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _loop_ops(module, opcodes, shape):
+    """For each while loop of `module`, the ops of `opcodes` with result
+    `shape` in its computations and in everything they call."""
+    def callees(i):
+        return [*i.called_computations, *_FUSED.findall(i.attr_text)]
+
+    loops = {}
+    for _, w in module.all_instructions():
+        if w.opcode != "while":
+            continue
+        todo, seen, found = callees(w), set(), []
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in module.computations:
+                continue
+            seen.add(name)
+            for i in module.computations[name].instructions:
+                todo.extend(callees(i))
+                if i.opcode in opcodes and any(d == shape for _, d in i.shapes):
+                    found.append((i.opcode, i.name))
+        loops[w.name] = found
+    assert loops, "the compiled solve has no while loop"
+    return loops
+
+
+# (mesh, block-sized copies a loop may hold). On 2x2 the compiler lays the
+# carried blocks out for the column halos and copies one back a trip; the
+# concatenating schedule copied two a sweep.
+@pytest.mark.parametrize("mesh_shape,copies", [((1, 1), 0), ((2, 2), 1)],
+                         ids=["1x1", "2x2"])
+def test_heat2d_hdot_loop_writes_blocks_in_place(topo, one_chip, mesh_shape,
+                                                 copies):
+    """The hdot sweep loop writes each task's cells into the carried spare
+    block: no block-sized concatenate inside it, and no block-sized copy on
+    1x1 (at most one on 2x2)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.analysis.hlo_ir import parse_hlo_module
+    from repro.core.stencil import _heat2d_solver
+    from repro.launch.mesh import GRID_AXES
+
+    n = 2048  # cells a chip along each dim
+    devs = np.array(topo.devices[:int(np.prod(mesh_shape))]).reshape(mesh_shape)
+    mesh = Mesh(devs, GRID_AXES)
+    solve = _heat2d_solver(mesh, GRID_AXES, 10, "hdot", 4, None)
+    glob = tuple(n * m for m in mesh_shape)
+    c = solve.lower(_spec(NamedSharding(mesh, P(*GRID_AXES)), glob)).compile()
+    module = parse_hlo_module(c.as_text())
+    loops = _loop_ops(module, ("copy", "copy-start", "concatenate"), (n, n))
+    for ops in loops.values():
+        assert "concatenate" not in {op for op, _ in ops}, loops
+        assert len(ops) <= copies, loops
