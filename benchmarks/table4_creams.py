@@ -1,16 +1,17 @@
-"""Paper Table 4: CREAMS (RK3 + 8th-order stencils) hybrid vs pure-MPI gain.
+"""Paper Table 4: CREAMS (compressible flow, RK3) hybrid vs pure-MPI gain.
 
 The paper's Sod-tube domain is 20x20x7000 decomposed along z; the hybrid gain
 grows from +2.6% (1 node) to +13.3% (16 nodes) because the HDOT schedule
-hides the halo exchange behind the per-direction stencil tasks.
+hides the halo exchange behind the per-direction flux tasks.
 
-Here: rk3_solve (8th-order, width-4 halos, Williamson RK3 — core/stencil) on
-1..8 virtual devices, both schedules; wall clock + per-step collective wire
-bytes. The x/y stencils are the "other tasks" that hide the z-halo ppermute,
-exactly Figure 5's dependency graph. ``--mesh RxC`` switches to the 2-D
-(y, z) grid-mesh decomposition (stage-carried halos on BOTH axes; the y
-extent is scaled with the row count so every shard keeps the width-4
-pipelined path alive).
+Here: rk3_solve (compressible Euler, LLF-split WENO5 flux tasks with width-3
+halos of the 5-component state, Williamson RK3 with a CFL dt — core/stencil)
+on the Taylor-Green vortex, 1..8 virtual devices, both schedules; wall clock
++ per-step collective wire bytes. The x/y flux tasks are the "other tasks"
+that hide the z-halo ppermute, exactly Figure 5's dependency graph.
+``--mesh RxC`` switches to the 2-D (y, z) grid-mesh decomposition
+(stage-carried halos on BOTH axes; the y extent is scaled with the row count
+so every shard keeps the pipelined path alive).
 """
 from __future__ import annotations
 
@@ -21,12 +22,11 @@ from typing import Any, Dict
 def worker(devices: int, nz: int, steps: int,
            mesh_shape: str = "") -> Dict[str, Any]:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from benchmarks._util import parse_mesh_shape, timeit
     from repro.analysis.hlo import parse_collectives
-    from repro.core.stencil import rk3_solve
+    from repro.core.stencil import euler_tgv_init, rk3_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
 
     if mesh_shape:
@@ -34,15 +34,14 @@ def worker(devices: int, nz: int, steps: int,
         assert ry * rz == devices, (mesh_shape, devices)
         mesh = make_grid_mesh(ry, rz)
         axis = ("rows", "cols")
-        # >= 32 y-cells per row shard keeps the width-4 pipelined path alive
+        # >= 32 y-cells per row shard keeps the pipelined path alive
         shape = (20, 32 * ry, nz)
     else:
         mesh = make_mesh((devices,), ("data",))
         axis = ("data",)
         # paper: 20 x 20 x 7000; scaled-down x/y for CPU wall clock
         shape = (20, 20, nz)
-    key = jax.random.PRNGKey(0)
-    v0 = jax.random.normal(key, shape, jnp.float32)
+    v0 = euler_tgv_init(shape)
     out: Dict[str, Any] = {"devices": devices, "nz": nz, "steps": steps}
     if mesh_shape:
         out["mesh_shape"] = mesh_shape
@@ -52,7 +51,7 @@ def worker(devices: int, nz: int, steps: int,
             return rk3_solve(v0, mesh, axis, steps, mode=mode)
 
         sec = timeit(solve)
-        results[mode] = np.asarray(solve())
+        results[mode] = np.asarray(solve()[0])
         lowered = jax.jit(
             lambda v: rk3_solve(v, mesh, axis, 1, mode=mode)).lower(v0)
         coll = parse_collectives(lowered.compile().as_text())

@@ -228,17 +228,19 @@ def _heat2d_weighted() -> Target:
 
 @target("rk3_1d")
 def _rk3_1d() -> Target:
-    """RK3 advection, z-slab decomposition over 4 devices, steps=2."""
+    """RK3 compressible Euler (LLF-split WENO5 fluxes, 5-component state),
+    z-slab decomposition over 4 devices, steps=2."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.stencil import _rk3_solver
     from repro.launch.mesh import make_mesh
 
-    # global dim 2 = 64 so the local shard keeps >= 16 cells (the pipelined
-    # stage-carried path; smaller shards take the per-step fallback)
-    f = _rk3_solver(make_mesh((4,), ("data",)), ("data",), 2, 0.01, "hdot")
-    txt = _pre_opt_text(f, jax.ShapeDtypeStruct((12, 16, 64), jnp.float32))
+    # global dim 3 (z) = 64 so the local shard keeps >= 4 * width cells (the
+    # pipelined stage-carried path; smaller shards take the per-step fallback)
+    f = _rk3_solver(make_mesh((4,), ("data",)), ("data",), 2, (1.0,) * 3,
+                    "hdot")
+    txt = _pre_opt_text(f, jax.ShapeDtypeStruct((5, 12, 16, 64), jnp.float32))
     return Target("rk3_1d", txt,
                   LintContext(target="rk3_1d",
                               expected_permute_total=PERMUTES_RK3(1, 2)))
@@ -246,15 +248,17 @@ def _rk3_1d() -> Target:
 
 @target("rk3_2d")
 def _rk3_2d() -> Target:
-    """RK3 on a (y, z) 2x2 grid mesh, stage-carried halos on both axes."""
+    """RK3 compressible Euler on a (y, z) 2x2 grid mesh, stage-carried halos
+    of all five components on both axes."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.stencil import _rk3_solver
     from repro.launch.mesh import make_grid_mesh
 
-    f = _rk3_solver(make_grid_mesh(2, 2), ("rows", "cols"), 2, 0.01, "hdot")
-    txt = _pre_opt_text(f, jax.ShapeDtypeStruct((12, 32, 32), jnp.float32))
+    f = _rk3_solver(make_grid_mesh(2, 2), ("rows", "cols"), 2, (1.0,) * 3,
+                    "hdot")
+    txt = _pre_opt_text(f, jax.ShapeDtypeStruct((5, 12, 32, 32), jnp.float32))
     return Target("rk3_2d", txt,
                   LintContext(target="rk3_2d",
                               expected_permute_total=PERMUTES_RK3(2, 2)))

@@ -155,7 +155,7 @@ def pad_with_halo(u: jax.Array, axis_name: str, width: int, dim: int,
 # ends of EVERY dim in `dims` and must return the updated un-padded block.
 # "Star"-shaped stencils only: corners between two decomposed dims are never
 # exchanged (sufficient for the paper's Heat2D 5-point and CREAMS
-# per-direction WENO stencils; HPCCG's 27-point corner couplings ride the
+# per-direction WENO5 flux tasks; HPCCG's 27-point corner couplings ride the
 # sequential face-message chain in core/stencil.py instead).
 #
 # Partition of a block with extents (n_0 .. n_{N-1}) along the decomposed
@@ -746,23 +746,31 @@ def multi_dim_stencil(u: jax.Array,
                       width: int, periodic: bool = False,
                       mode: str = "hdot") -> jax.Array:
     """Apply a direction-split stencil along several decomposed dims (the
-    CREAMS pattern: euler_LLF_x/y/z are separate per-direction stencils whose
+    CREAMS pattern: euler_LLF_x/y/z are separate per-direction tasks whose
     results sum). `decomp` lists (dim, mesh_axis_or_None); un-sharded dims use
-    a local pad."""
+    a local pad, and the task on the padded block runs as the interior. Dims
+    left out of `decomp` (a leading component axis) ride along whole."""
     total = None
     for dim, axis_name in decomp:
         fn = partial(per_dim_fn, dim=dim)
         if axis_name is None:
-            if periodic:
-                padded = jnp.concatenate(
-                    [_edge(u, dim, "hi", width), u, _edge(u, dim, "lo", width)], axis=dim)
-            else:
-                pads = [(0, 0)] * u.ndim
-                pads[dim] = (width, width)
-                padded = jnp.pad(u, pads)
-            out = fn(padded)
+            with jax.named_scope(EXCHANGE):
+                if periodic:
+                    padded = jnp.concatenate(
+                        [_edge(u, dim, "hi", width), u,
+                         _edge(u, dim, "lo", width)], axis=dim)
+                else:
+                    pads = [(0, 0)] * u.ndim
+                    pads[dim] = (width, width)
+                    padded = jnp.pad(u, pads)
+            with jax.named_scope(INTERIOR):
+                out = fn(padded)
         else:
             out = stencil_apply_nd(u, fn, ((axis_name, dim),), width,
                                    periodic, mode, (4,))
-        total = out if total is None else total + out
+        if total is None:
+            total = out
+        else:
+            with jax.named_scope(UPDATE):
+                total = total + out
     return total

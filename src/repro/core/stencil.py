@@ -1,5 +1,6 @@
-"""The paper's applications (§4) rebuilt on the HDOT core: Heat2D, a
-CREAMS-like RK3 multi-direction stencil, and HPCCG's preconditioned CG.
+"""The paper's applications (§4) rebuilt on the HDOT core: Heat2D, CREAMS as
+a compressible Euler solver (LLF-split WENO5 fluxes per direction, RK3 in
+time), and HPCCG's preconditioned CG.
 
 Each app exposes the SAME solver under the two schedules
 (``mode='two_phase'`` = paper's MPI+OpenMP baseline, ``mode='hdot'``), so the
@@ -15,6 +16,7 @@ reductions and boundary/interior splits.
 from __future__ import annotations
 
 import functools
+import math
 import warnings
 from typing import Optional, Tuple
 
@@ -28,7 +30,7 @@ from repro.core.halo import (EXCHANGE, INTERIOR, REDUCE, UPDATE, _norm_subn,
                              exchange_halo, halo_scan_nd, multi_dim_stencil,
                              pad_with_halo, stencil_apply_nd,
                              stencil_with_halo_nd)
-from repro.core.reduction import task_reduce
+from repro.core.reduction import hdot_reduce, task_reduce
 
 _STR_AXES_WARNED: set = set()
 
@@ -203,182 +205,308 @@ def heat2d_init(nx: int, ny: int, dtype=jnp.float32) -> jax.Array:
     return u.at[cx - w:cx + w, cy - w:cy + w].set(1.0)
 
 
-# ========================================== CREAMS-like RK3 stencil (§4.2)
-# 8th-order central second-derivative coefficients (halo width 4 == CREAMS Nh).
-_C8 = jnp.array([-1 / 560, 8 / 315, -1 / 5, 8 / 5, -205 / 72, 8 / 5, -1 / 5, 8 / 315, -1 / 560])
+# ============================== CREAMS: compressible Euler, RK3 (§4.2)
+# The state is U = (rho, rho u, rho v, rho w, E) on a periodic box, shape
+# (5, nx, ny, nz): the component axis leads, array dims 1, 2, 3 are x, y, z.
+# Each direction's flux divergence is one task (the paper's euler_LLF_x/y/z):
+# local Lax-Friedrichs flux splitting with fifth-order WENO-JS reconstruction
+# (Jiang & Shu, J. Comput. Phys. 126, 1996; Shu, ICASE Report 97-65), whose
+# stencil reaches 3 cells past a cell on each side: the halo width.
+EULER_WIDTH = 3
+_WENO_EPS = 1e-6
+GAMMA = 1.4           # ratio of specific heats: one calorically perfect gas
+CFL = 0.5             # dt = CFL / max over cells of sum_d (|u_d| + c) / dx_d
+BOX = 2 * math.pi     # the periodic box [0, BOX)^3
 # classic Williamson low-storage RK3 coefficients
 _RK3_A = (0.0, -5 / 9, -153 / 128)
 _RK3_B = (1 / 3, 15 / 16, 8 / 15)
 
 
-def _diff2_dir(padded: jax.Array, dim: int) -> jax.Array:
-    """8th-order d2/dx_dim^2 over a block padded by 4 ghosts along `dim`."""
-    n = padded.shape[dim] - 8
-    out = None
-    for j, c in enumerate(_C8.tolist()):
-        sl = lax.slice_in_dim(padded, j, j + n, axis=dim)
-        out = c * sl if out is None else out + c * sl
-    return out
+def _primitives(u: jax.Array):
+    """Velocity (3, ...), pressure and sound speed of the conserved `u`."""
+    inv_rho = 1.0 / u[0]
+    vel = u[1:4] * inv_rho
+    p = (GAMMA - 1.0) * (u[4] - 0.5 * u[0] * jnp.sum(vel * vel, axis=0))
+    return vel, p, jnp.sqrt(GAMMA * p * inv_rho)
 
 
-def rk3_rhs(v: jax.Array, axis_name, mode: str,
-            nu: float = 0.05) -> jax.Array:
-    """Direction-split diffusion RHS (stands in for euler_LLF_x/y/z): the three
-    per-direction stencils are independent tasks (paper Figure 5). `axis_name`
-    is one mesh axis (z decomposed) or a (y_axis, z_axis) pair — each
-    direction's stencil only ever needs its OWN axis's halo (direction-split
-    stencils have no cross-dim couplings), so a 2-D mesh needs no corner
-    messages at all."""
+def _weno5(a, b, c, d, e):
+    """WENO5-JS value at the face between `c` and `d` from the five cell
+    values a..e, biased to the left: linear weights 1/10, 6/10, 3/10,
+    Jiang-Shu smoothness indicators, epsilon 1e-6, power 2."""
+    q0 = (2 * a - 7 * b + 11 * c) / 6
+    q1 = (-b + 5 * c + 2 * d) / 6
+    q2 = (2 * c + 5 * d - e) / 6
+    s0 = 13 / 12 * (a - 2 * b + c) ** 2 + 0.25 * (a - 4 * b + 3 * c) ** 2
+    s1 = 13 / 12 * (b - 2 * c + d) ** 2 + 0.25 * (b - d) ** 2
+    s2 = 13 / 12 * (c - 2 * d + e) ** 2 + 0.25 * (3 * c - 4 * d + e) ** 2
+    w0 = 0.1 / (_WENO_EPS + s0) ** 2
+    w1 = 0.6 / (_WENO_EPS + s1) ** 2
+    w2 = 0.3 / (_WENO_EPS + s2) ** 2
+    return (w0 * q0 + w1 * q1 + w2 * q2) / (w0 + w1 + w2)
+
+
+def euler_llf(padded: jax.Array, dim: int,
+              inv_dx: Tuple[float, ...]) -> jax.Array:
+    """The paper's euler_LLF_d task: -dF_d/dx_d on the cells of a state block
+    padded by EULER_WIDTH ghosts at both ends of array dim `dim` (1, 2, 3 =
+    x, y, z); `inv_dx` holds 1/dx of each direction.
+
+    At each face i+1/2 the splitting speed alpha is the largest |u_d| + c of
+    the six cells i-2..i+3 of the face's stencil; F+- = (F +- alpha U) / 2
+    are formed on those cells, F+ reconstructed by WENO5-JS from the left
+    (cells i-2..i+2) and F- mirrored from the right (cells i-1..i+3). The
+    five components travel together, so the task reads the whole block."""
+    w = EULER_WIDTH
+    n = padded.shape[dim] - 2 * w
+    vel, p, c = _primitives(padded)
+    ud = vel[dim - 1]
+    mom = [padded[1 + j] * ud for j in range(3)]
+    mom[dim - 1] = mom[dim - 1] + p
+    flux = jnp.stack([padded[dim], *mom, (padded[4] + p) * ud])
+    speed = (jnp.abs(ud) + c)[None]
+
+    def at(x, k):
+        # x on cell i - 2 + k, for each of the n + 1 faces i + 1/2
+        return lax.slice_in_dim(x, k, k + n + 1, axis=dim)
+
+    alpha = functools.reduce(jnp.maximum, [at(speed, k) for k in range(2 * w)])
+    plus = [0.5 * (at(flux, k) + alpha * at(padded, k)) for k in range(2 * w - 1)]
+    minus = [0.5 * (at(flux, k) - alpha * at(padded, k)) for k in range(1, 2 * w)]
+    face = _weno5(*plus) + _weno5(*minus[::-1])
+    return (lax.slice_in_dim(face, 0, n, axis=dim)
+            - lax.slice_in_dim(face, 1, n + 1, axis=dim)) * inv_dx[dim - 1]
+
+
+def _cfl_dt(u: jax.Array, inv_dx, axes, subdomains: int = 4) -> jax.Array:
+    """dt = CFL / max over cells of sum_d (|u_d| + c) / dx_d, from the state
+    at a step's start: each task's partial max over its slab of z, then the
+    max over the tasks and the mesh (paper Code 5). No stage of the step can
+    update before it."""
+    with jax.named_scope(REDUCE):
+        parts = []
+        for blk in jnp.array_split(u, min(subdomains, u.shape[3]), axis=3):
+            vel, _, c = _primitives(blk)
+            rate = (jnp.abs(vel[0]) + c) * inv_dx[0]
+            for d in (1, 2):
+                rate = rate + (jnp.abs(vel[d]) + c) * inv_dx[d]
+            parts.append(jnp.max(rate))
+        return CFL / hdot_reduce(parts, axes, "max")
+
+
+def _llf_task(inv_dx, dim=None):
+    fn = functools.partial(euler_llf, inv_dx=inv_dx)
+    return fn if dim is None else functools.partial(fn, dim=dim)
+
+
+def _rk3_stage(u, s, rhs, dt, a: float, b: float):
+    """The low-storage stage update: S = a S + dt rhs; U = U + b S."""
+    with jax.named_scope(UPDATE):
+        s = dt * rhs if s is None else a * s + dt * rhs
+        return u + b * s, s
+
+
+def _euler_rhs(u: jax.Array, axis_name, mode: str, inv_dx) -> jax.Array:
+    """The three flux tasks (paper Figure 5), each stage's halos exchanged
+    inside the stage. `axis_name` is one mesh axis (z decomposed) or a
+    (y_axis, z_axis) pair; each direction's task needs only its OWN axis's
+    halo (direction-split fluxes have no cross-dim couplings), so a 2-D mesh
+    needs no corner messages."""
     if isinstance(axis_name, tuple):
         ay, az = axis_name
-        decomp = [(0, None), (1, ay), (2, az)]
+        decomp = [(1, None), (2, ay), (3, az)]
     else:
-        decomp = [(0, None), (1, None), (2, axis_name)]
-    return nu * multi_dim_stencil(v, _diff2_dir, decomp, width=4,
-                                  periodic=True, mode=mode)
+        decomp = [(1, None), (2, None), (3, axis_name)]
+    return multi_dim_stencil(u, _llf_task(inv_dx), decomp,
+                             width=EULER_WIDTH, periodic=True, mode=mode)
 
 
-def _rk3_rhs_with_halo(v: jax.Array, lo: jax.Array, hi: jax.Array,
-                       nu: float = 0.05, subdomains: int = 4) -> jax.Array:
-    """RHS with z-halos already in hand (pipelined schedule): the x/y stencils
-    are multi_dim_stencil's local-pad tasks, the z stencil consumes the
-    carried halos — no exchange on this stage's critical path."""
-    xy = multi_dim_stencil(v, _diff2_dir, [(0, None), (1, None)], width=4,
+def _euler_rhs_with_halo(u: jax.Array, lo: jax.Array, hi: jax.Array, inv_dx,
+                         subdomains: int = 4) -> jax.Array:
+    """RHS with z-halos already in hand (pipelined schedule): the x/y tasks
+    pad locally, the z task's faces consume the carried halos — no exchange
+    on this stage's critical path."""
+    xy = multi_dim_stencil(u, _llf_task(inv_dx),
+                           [(1, None), (2, None)], width=EULER_WIDTH,
                            periodic=True)
-    z = stencil_with_halo_nd(v, [(lo, hi)], functools.partial(_diff2_dir, dim=2),
-                             width=4, dims=(2,), subdomains=(subdomains,))
-    return nu * (xy + z)
+    z = stencil_with_halo_nd(u, [(lo, hi)], _llf_task(inv_dx, 3),
+                             width=EULER_WIDTH, dims=(3,),
+                             subdomains=(subdomains,))
+    with jax.named_scope(UPDATE):
+        return xy + z
 
 
-def _rk3_rhs_with_halo_2d(v: jax.Array, hy, hz, nu: float = 0.05,
-                          subdomains: int = 4) -> jax.Array:
+def _euler_rhs_with_halo_2d(u: jax.Array, hy, hz, inv_dx,
+                            subdomains: int = 4) -> jax.Array:
     """RHS with BOTH mesh axes' halos already in hand ((y, z) grid mesh):
-    the x stencil is a local-pad task; the y and z stencils each consume
-    their own carried halo pair — neither exchange sits on this stage's
-    critical path, and the per-direction interior chunks are the independent
-    work both ppermute pairs hide behind."""
-    x = multi_dim_stencil(v, _diff2_dir, [(0, None)], width=4, periodic=True)
-    y = stencil_with_halo_nd(v, [hy], functools.partial(_diff2_dir, dim=1),
-                             width=4, dims=(1,), subdomains=(subdomains,))
-    z = stencil_with_halo_nd(v, [hz], functools.partial(_diff2_dir, dim=2),
-                             width=4, dims=(2,), subdomains=(subdomains,))
-    return nu * (x + y + z)
+    the x task pads locally; the y and z tasks' faces each consume their own
+    carried halo pair — neither exchange sits on this stage's critical path,
+    and the per-direction interior chunks are the independent work both
+    ppermute pairs hide behind."""
+    x = multi_dim_stencil(u, _llf_task(inv_dx), [(1, None)],
+                          width=EULER_WIDTH, periodic=True)
+    y = stencil_with_halo_nd(u, [hy], _llf_task(inv_dx, 2),
+                             width=EULER_WIDTH, dims=(2,),
+                             subdomains=(subdomains,))
+    z = stencil_with_halo_nd(u, [hz], _llf_task(inv_dx, 3),
+                             width=EULER_WIDTH, dims=(3,),
+                             subdomains=(subdomains,))
+    with jax.named_scope(UPDATE):
+        return x + y + z
 
 
-def rk3_local_step(v: jax.Array, axis_name: Optional[str], dt: float,
-                   mode: str) -> jax.Array:
-    """One 3-stage low-storage RK step (paper Code 8's rk loop): each stage is
-    data-prep -> per-direction stencils -> update -> halo comm, with the HDOT
-    schedule overlapping the z-direction halo with the x/y stencil tasks."""
-    s = jnp.zeros_like(v)
+def rk3_local_step(u: jax.Array, axis_name, mode: str,
+                   inv_dx) -> Tuple[jax.Array, jax.Array]:
+    """One 3-stage low-storage RK step (paper Code 8's rk loop): the CFL dt
+    from the step's state, then per stage data-prep -> halo comm ->
+    per-direction flux tasks -> update. Returns (U, dt)."""
+    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
+    dt = _cfl_dt(u, inv_dx, axes)
+    s = None
     for a, b in zip(_RK3_A, _RK3_B):
-        rhs = rk3_rhs(v, axis_name, mode)
-        s = a * s + dt * rhs
-        v = v + b * s
-    return v
+        u, s = _rk3_stage(u, s, _euler_rhs(u, axis_name, mode, inv_dx), dt,
+                          a, b)
+    return u, dt
 
 
-def rk3_local_step_pipelined(v: jax.Array, lo: jax.Array, hi: jax.Array,
-                             axis_name: str, dt: float,
-                             subdomains: int = 4, exchange_last: bool = True
-                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+def rk3_local_step_pipelined(u: jax.Array, lo: jax.Array, hi: jax.Array,
+                             axis_name: str, inv_dx, subdomains: int = 4,
+                             exchange_last: bool = True):
     """RK3 step with z-halos carried across stages: each stage consumes the
     halos exchanged at the END of the previous stage, and launches the next
-    exchange the moment its `v` update lands — so every z ppermute flies
-    behind the next stage's x/y stencils and interior z chunks (the
-    double-buffered analogue of Code 8's comm task). `exchange_last=False`
-    peels the drain: the solve's final stage feeds no consumer, so its
-    exchange would be a dead width-4 ppermute pair."""
-    s = jnp.zeros_like(v)
-    n_stages = len(_RK3_A)
+    exchange the moment its U update lands — so every z ppermute pair (all
+    five components in one pair) flies behind the next stage's x/y tasks and
+    interior z chunks (the double-buffered analogue of Code 8's comm task).
+    `exchange_last=False` peels the drain: the solve's final stage feeds no
+    consumer, so its exchange would be a dead ppermute pair. Returns
+    (U, lo, hi, dt)."""
+    dt = _cfl_dt(u, inv_dx, (axis_name,), subdomains)
+    s = None
     for i, (a, b) in enumerate(zip(_RK3_A, _RK3_B)):
-        rhs = _rk3_rhs_with_halo(v, lo, hi, subdomains=subdomains)
-        s = a * s + dt * rhs
-        v = v + b * s
-        if exchange_last or i < n_stages - 1:
-            lo, hi = exchange_halo(v, axis_name, width=4, dim=2, periodic=True)
-    return v, lo, hi
+        rhs = _euler_rhs_with_halo(u, lo, hi, inv_dx, subdomains)
+        u, s = _rk3_stage(u, s, rhs, dt, a, b)
+        if exchange_last or i < len(_RK3_A) - 1:
+            lo, hi = exchange_halo(u, axis_name, EULER_WIDTH, dim=3,
+                                   periodic=True)
+    return u, lo, hi, dt
 
 
-def rk3_local_step_pipelined_2d(v: jax.Array, hy, hz, ay: str, az: str,
-                                dt: float, subdomains: int = 4,
+def rk3_local_step_pipelined_2d(u: jax.Array, hy, hz, ay: str, az: str,
+                                inv_dx, subdomains: int = 4,
                                 exchange_last: bool = True):
     """RK3 step on a (y, z) grid mesh with BOTH axes' halos carried across
     stages: each stage consumes the pairs exchanged at the END of the
     previous stage and launches the next y AND z exchanges the moment its
-    `v` update lands — so every ppermute pair flies behind the next stage's
-    x stencil and the y/z interior chunks. `exchange_last=False` peels the
-    drain (the solve's final stage feeds no consumer — two dead width-4
-    pairs saved per solve)."""
-    s = jnp.zeros_like(v)
-    n_stages = len(_RK3_A)
+    U update lands — so every ppermute pair flies behind the next stage's
+    x task and the y/z interior chunks. `exchange_last=False` peels the
+    drain (the solve's final stage feeds no consumer — two dead pairs saved
+    per solve). Returns (U, hy, hz, dt)."""
+    dt = _cfl_dt(u, inv_dx, (ay, az), subdomains)
+    s = None
     for i, (a, b) in enumerate(zip(_RK3_A, _RK3_B)):
-        rhs = _rk3_rhs_with_halo_2d(v, hy, hz, subdomains=subdomains)
-        s = a * s + dt * rhs
-        v = v + b * s
-        if exchange_last or i < n_stages - 1:
-            hy = exchange_halo(v, ay, width=4, dim=1, periodic=True)
-            hz = exchange_halo(v, az, width=4, dim=2, periodic=True)
-    return v, hy, hz
+        rhs = _euler_rhs_with_halo_2d(u, hy, hz, inv_dx, subdomains)
+        u, s = _rk3_stage(u, s, rhs, dt, a, b)
+        if exchange_last or i < len(_RK3_A) - 1:
+            hy = exchange_halo(u, ay, EULER_WIDTH, dim=2, periodic=True)
+            hz = exchange_halo(u, az, EULER_WIDTH, dim=3, periodic=True)
+    return u, hy, hz, dt
+
+
+def euler_tgv_init(shape) -> jax.Array:
+    """The compressible Taylor-Green vortex at Mach 0.1 (rho0 = U0 = 1) on
+    the periodic box [0, BOX)^3, sampled at x_i = i dx, as the float32
+    (5, nx, ny, nz) state rk3_solve takes: u = sin x cos y cos z,
+    v = -cos x sin y cos z, w = 0, p = p0 + (cos 2x + cos 2y)(cos 2z + 2) / 16,
+    rho = p / p0 with p0 = 1 / (GAMMA Ma^2)."""
+    x, y, z = jnp.meshgrid(*[jnp.arange(n, dtype=jnp.float32) * (BOX / n)
+                             for n in shape], indexing="ij")
+    p0 = 1 / (GAMMA * 0.1 ** 2)
+    u = jnp.sin(x) * jnp.cos(y) * jnp.cos(z)
+    v = -jnp.cos(x) * jnp.sin(y) * jnp.cos(z)
+    p = p0 + (jnp.cos(2 * x) + jnp.cos(2 * y)) * (jnp.cos(2 * z) + 2) / 16
+    rho = p / p0
+    e = p / (GAMMA - 1) + 0.5 * rho * (u * u + v * v)
+    return jnp.stack([rho, rho * u, rho * v, jnp.zeros_like(u), e])
 
 
 @functools.lru_cache(maxsize=128)
-def _rk3_solver(mesh, axes, steps: int, dt: float, mode: str):
+def _rk3_solver(mesh, axes, steps: int, inv_dx: Tuple[float, ...],
+                mode: str):
     axes = normalize_mesh_axes(axes, "rk3_solve", (1, 2))
     two_d = len(axes) == 2
     ay, az = axes if two_d else (None, None)
     axis_name = axes if two_d else axes[0]
+    w = EULER_WIDTH
 
-    def local(v):
-        if (two_d and mode == "hdot" and v.shape[1] >= 16
-                and v.shape[2] >= 16 and steps > 0):
-            hy = exchange_halo(v, ay, width=4, dim=1, periodic=True)
-            hz = exchange_halo(v, az, width=4, dim=2, periodic=True)
+    def local(u):
+        if (two_d and mode == "hdot" and u.shape[2] >= 4 * w
+                and u.shape[3] >= 4 * w and steps > 0):
+            hy = exchange_halo(u, ay, w, dim=2, periodic=True)
+            hz = exchange_halo(u, az, w, dim=3, periodic=True)
 
             def body(carry, _):
-                v, hy, hz = carry
-                return rk3_local_step_pipelined_2d(v, hy, hz, ay, az, dt), None
+                u, hy, hz, dt = rk3_local_step_pipelined_2d(*carry, ay, az,
+                                                            inv_dx)
+                return (u, hy, hz), dt
 
             # drain peeled: the last step's last-stage exchanges are dead
-            (v, hy, hz), _ = lax.scan(body, (v, hy, hz), None,
-                                      length=steps - 1)
-            v, _, _ = rk3_local_step_pipelined_2d(v, hy, hz, ay, az, dt,
-                                                  exchange_last=False)
-            return v
+            (u, hy, hz), dts = lax.scan(body, (u, hy, hz), None,
+                                        length=steps - 1)
+            u, _, _, dt = rk3_local_step_pipelined_2d(u, hy, hz, ay, az,
+                                                      inv_dx,
+                                                      exchange_last=False)
+            return u, jnp.concatenate([dts, dt[None]])
 
-        if not two_d and mode == "hdot" and v.shape[2] >= 16 and steps > 0:
-            lo, hi = exchange_halo(v, axis_name, width=4, dim=2,
+        if not two_d and mode == "hdot" and u.shape[3] >= 4 * w and steps > 0:
+            lo, hi = exchange_halo(u, axis_name, w, dim=3,
                                    periodic=True)  # pipeline fill
 
             def body(carry, _):
-                return rk3_local_step_pipelined(*carry, axis_name, dt), None
+                u, lo, hi, dt = rk3_local_step_pipelined(*carry, axis_name,
+                                                         inv_dx)
+                return (u, lo, hi), dt
 
             # drain peeled: the last step's last-stage exchange is dead
-            (v, lo, hi), _ = lax.scan(body, (v, lo, hi), None,
-                                      length=steps - 1)
-            v, _, _ = rk3_local_step_pipelined(v, lo, hi, axis_name, dt,
-                                               exchange_last=False)
-            return v
+            (u, lo, hi), dts = lax.scan(body, (u, lo, hi), None,
+                                        length=steps - 1)
+            u, _, _, dt = rk3_local_step_pipelined(u, lo, hi, axis_name,
+                                                   inv_dx, exchange_last=False)
+            return u, jnp.concatenate([dts, dt[None]])
 
-        def body(v, _):
-            return rk3_local_step(v, axis_name, dt, mode), None
-        v, _ = lax.scan(body, v, None, length=steps)
-        return v
+        def body(u, _):
+            return rk3_local_step(u, axis_name, mode, inv_dx)
+        return lax.scan(body, u, None, length=steps)
 
-    spec = P(None, ay, az) if two_d else P(None, None, axis_name)
-    f = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
+    spec = P(None, None, ay, az) if two_d else P(None, None, None, axis_name)
+    f = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=(spec, P()))
     return jax.jit(f)
 
 
-def rk3_solve(v0: jax.Array, mesh, mesh_axes, steps: int, dt: float = 0.05,
-              mode: str = "hdot") -> jax.Array:
-    """Run `steps` RK3 steps. `mesh_axes` is the unified solver topology
-    contract: ``(z_axis,)`` — the paper's z-decomposed slabs — or a
-    ``(y_axis, z_axis)`` pair — true 2-D (y, z) grid-mesh decomposition with
-    stage-carried halos on BOTH axes (each direction-split stencil consumes
-    only its own axis's pair, so the 2-D mesh needs no corner messages)."""
-    axes = normalize_mesh_axes(mesh_axes, "rk3_solve", (1, 2))
-    return _rk3_solver(mesh, axes, steps, dt, mode)(v0)
+def rk3_solve(u0: jax.Array, mesh, mesh_axes, steps: int,
+              mode: str = "hdot") -> Tuple[jax.Array, jax.Array]:
+    """Run `steps` RK3 steps of the compressible Euler equations (CREAMS,
+    paper §4.2) on the periodic box [0, BOX)^3; returns (U after `steps`
+    steps, the dt of each step).
+
+    `u0` is the GLOBAL conserved state (rho, rho u, rho v, rho w, E) of shape
+    (5, nx, ny, nz); p = (GAMMA - 1)(E - rho |u|^2 / 2). Each step takes
+    dt = CFL / max over cells of sum_d (|u_d| + c) / dx_d from its starting
+    state (a max over the mesh), then three Williamson low-storage RK stages,
+    each summing the three directions' LLF-split WENO5 flux tasks.
+
+    `mesh_axes` is the unified solver topology contract: ``(z_axis,)`` — the
+    paper's z-decomposed slabs — or a ``(y_axis, z_axis)`` pair — true 2-D
+    (y, z) grid-mesh decomposition with stage-carried halos on BOTH axes
+    (each direction-split task consumes only its own axis's pair, so the 2-D
+    mesh needs no corner messages). One ppermute pair per axis per stage
+    carries all five components."""
+    with jax.profiler.TraceAnnotation(SOLVE_SPAN):
+        axes = normalize_mesh_axes(mesh_axes, "rk3_solve", (1, 2))
+        if u0.ndim != 4 or u0.shape[0] != 5:
+            raise ValueError(f"rk3_solve: the state is (5, nx, ny, nz), got "
+                             f"shape {u0.shape}")
+        inv_dx = tuple(n / BOX for n in u0.shape[1:])
+        return _rk3_solver(mesh, axes, steps, inv_dx, mode)(u0)
 
 
 # ============================================================ HPCCG CG (§4.3)

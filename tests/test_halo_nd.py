@@ -208,18 +208,21 @@ def test_exchange_halo_nd_periodic_wraps_own_edges(grid_mesh3):
 
 def test_rk3_2d_mesh_matches_slab(grid_mesh3):
     """rk3_solve on a 1x1 (rows, cols) topology == the z-slab solver, both
-    schedules (stage-carried halos on BOTH axes)."""
+    schedules (stage-carried halos of the 5-component state on BOTH axes)."""
     from repro.core.stencil import rk3_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
+    from tests.euler_reference import random_state
 
-    v0 = jax.random.normal(jax.random.PRNGKey(3), (12, 20, 32), jnp.float32)
-    want = rk3_solve(v0, make_mesh((1,), ("data",)), "data", 4, dt=0.01,
-                     mode="two_phase")
+    u0 = random_state(jax.random.PRNGKey(3), (12, 20, 32))
+    want, dt_want = rk3_solve(u0, make_mesh((1,), ("data",)), "data", 4,
+                              mode="two_phase")
     for mode in ("two_phase", "hdot"):
-        got = rk3_solve(v0, make_grid_mesh(1, 1), ("rows", "cols"), 4,
-                        dt=0.01, mode=mode)
+        got, dt = rk3_solve(u0, make_grid_mesh(1, 1), ("rows", "cols"), 4,
+                            mode=mode)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(dt), np.asarray(dt_want),
+                                   rtol=1e-6)
 
 
 def test_hpccg_3d_mesh_matches_slab(grid_mesh3):

@@ -39,6 +39,9 @@ CASES = {
     ("hpccg", (1, 1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},
     ("hpccg", (1, 2, 2), "hdot"): HDOT | {halo.UPDATE},
     ("hpccg", (1, 2, 2), "two_phase"): TWO_PHASE | {halo.UPDATE},
+    # CREAMS's flux tasks, CFL max and stage updates take the same six names
+    ("creams", (1, 1), "hdot"): HDOT | {halo.UPDATE},
+    ("creams", (1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},
 }
 MULTI = [c for c in CASES if np.prod(c[1]) > 1]
 
@@ -60,15 +63,20 @@ def innermost_stages(hlo_text: str) -> set:
 
 def compiled_stages(app: str, shape, mode: str) -> set:
     """Stages named in the compiled solve of `app` on a mesh of `shape`, at
-    a tiny size (each chip holds 32^2 cells, or 8^3)."""
+    a tiny size (each chip holds 32^2 cells, 8^3, or 5 fields of 8x16x16)."""
     mesh = make_grid_mesh(*shape, devices=jax.devices()[:int(np.prod(shape))])
     if app == "heat2d":
         fn = stencil._heat2d_solver(mesh, GRID_AXES, 4, mode, 4, None)
         local, spec = (32, 32), P(*GRID_AXES)
+    elif app == "creams":
+        fn = stencil._rk3_solver(mesh, GRID_AXES, 2, (1.0,) * 3, mode)
+        local, spec = (5, 8, 16, 16), P(None, None, *GRID_AXES)
     else:
         fn = stencil._hpccg_solver(mesh, GRID_AXES_3D, 3, mode, 4)
         local, spec = (8, 8, 8), P(*GRID_AXES_3D)
-    arg = jax.ShapeDtypeStruct(tuple(n * m for n, m in zip(local, shape)),
+    # the mesh splits the trailing dims (a leading field axis stays whole)
+    split = (1,) * (len(local) - len(shape)) + tuple(shape)
+    arg = jax.ShapeDtypeStruct(tuple(n * m for n, m in zip(local, split)),
                                jnp.float32, sharding=NamedSharding(mesh, spec))
     return innermost_stages(fn.lower(arg).compile().as_text())
 
@@ -93,11 +101,15 @@ def _host_spans(log_dir: str) -> list:
             for ln in p.lines for e in ln.events]
 
 
-@pytest.mark.parametrize("app", ["heat2d", "hpccg"])
+@pytest.mark.parametrize("app", ["heat2d", "hpccg", "creams"])
 def test_solver_entry_records_host_span(app, tmp_path):
     if app == "heat2d":
         mesh = make_grid_mesh(1, 1, devices=jax.devices()[:1])
         run = lambda: stencil.heat2d_solve(jnp.ones((16, 16)), mesh, GRID_AXES, 2)
+    elif app == "creams":
+        mesh = make_grid_mesh(1, 1, devices=jax.devices()[:1])
+        run = lambda: stencil.rk3_solve(stencil.euler_tgv_init((8, 16, 16)),
+                                        mesh, GRID_AXES, 2)
     else:
         mesh = make_grid_mesh(1, 1, 1, devices=jax.devices()[:1])
         run = lambda: stencil.hpccg_solve(jnp.ones((8, 8, 8)), mesh,

@@ -1,6 +1,6 @@
 """Paper applications on a single device: schedule equivalence (two_phase ==
 hdot numerics — the paper's key safety property), convergence, and physics
-sanity for Heat2D / RK3-CREAMS / HPCCG."""
+sanity for Heat2D / CREAMS (compressible Euler RK3) / HPCCG."""
 from __future__ import annotations
 
 import jax
@@ -46,20 +46,31 @@ def test_heat2d_jacobi_matches_numpy(data_mesh):
 
 
 def test_rk3_schedules_identical(data_mesh):
-    v0 = jax.random.normal(jax.random.PRNGKey(0), (12, 12, 32), jnp.float32)
-    v_tp = rk3_solve(v0, data_mesh, "data", 5, dt=0.01, mode="two_phase")
-    v_hd = rk3_solve(v0, data_mesh, "data", 5, dt=0.01, mode="hdot")
-    np.testing.assert_allclose(np.asarray(v_tp), np.asarray(v_hd),
+    """The Euler RK3 solver under both schedules: the same state and dt
+    history to float32 rounding (the flux tasks are cut differently)."""
+    from tests.euler_reference import random_state
+
+    u0 = random_state(jax.random.PRNGKey(0), (12, 12, 32))
+    u_tp, dt_tp = rk3_solve(u0, data_mesh, "data", 5, mode="two_phase")
+    u_hd, dt_hd = rk3_solve(u0, data_mesh, "data", 5, mode="hdot")
+    np.testing.assert_allclose(np.asarray(u_tp), np.asarray(u_hd),
                                rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dt_tp), np.asarray(dt_hd), rtol=1e-6)
 
 
 def test_rk3_diffusion_smooths(data_mesh):
-    """Periodic diffusion preserves the mean and contracts the variance."""
-    v0 = jax.random.normal(jax.random.PRNGKey(1), (8, 8, 64), jnp.float32)
-    v = rk3_solve(v0, data_mesh, "data", 20, dt=0.01, mode="hdot")
-    v0n, vn = np.asarray(v0), np.asarray(v)
-    assert vn.std() < v0n.std()
-    np.testing.assert_allclose(vn.mean(), v0n.mean(), atol=1e-4)
+    """Named for the diffusion stand-in the Euler solver replaced; it now
+    checks conservation on z slabs: flux differences telescope on the
+    periodic box, so the sums of rho, rho u, rho v, rho w and E change by
+    float32 rounding only, while the state itself moves."""
+    from tests.euler_reference import random_state
+
+    u0 = random_state(jax.random.PRNGKey(1), (8, 8, 64))
+    u, _ = rk3_solve(u0, data_mesh, "data", 20, mode="hdot")
+    u0n, un = np.asarray(u0, np.float64), np.asarray(u, np.float64)
+    drift = np.abs(un.sum(axis=(1, 2, 3)) - u0n.sum(axis=(1, 2, 3)))
+    assert np.all(drift <= 1e-7 * np.abs(u0n).sum(axis=(1, 2, 3))), drift
+    assert np.max(np.abs(un - u0n)) > 1e-3
 
 
 def test_hpccg_converges_and_schedules_match(data_mesh):

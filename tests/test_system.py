@@ -343,24 +343,28 @@ def test_heat2d_kernel_sharded_2x2_matches_unsharded():
 
 @pytest.mark.slow
 def test_rk3_2d_mesh_matches_1dev_oracle():
-    """RK3 on (y, z) grid meshes — stage-carried halos on BOTH axes — gives
-    the same field as the 1-device two-phase oracle, both schedules (2x2
-    exercises the pipelined two-axis path: 32-cell shards >= 4*width)."""
+    """The Euler RK3 solver on (y, z) grid meshes — stage-carried halos of
+    all five components on BOTH axes, the CFL dt a max over the mesh — gives
+    the 1-device two-phase oracle's state and dt history, both schedules
+    (every shard keeps >= 4 * width cells: the pipelined two-axis path)."""
     code = """
     import json, jax, jax.numpy as jnp, numpy as np
     from repro.core.stencil import rk3_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
-    v0 = jax.random.normal(jax.random.PRNGKey(0), (12, 64, 64), jnp.float32)
-    ref = rk3_solve(v0, make_mesh((1,), ("data",)), "data", 5, dt=0.01,
-                    mode="two_phase")
+    from tests.euler_reference import random_state
+    u0 = random_state(jax.random.PRNGKey(0), (8, 48, 48))
+    ref, dt_ref = rk3_solve(u0, make_mesh((1,), ("data",)), "data", 5,
+                            mode="two_phase")
     ok = {}
     for rc in ((2, 2), (4, 1), (1, 4)):
         for mode in ("two_phase", "hdot"):
-            got = rk3_solve(v0, make_grid_mesh(*rc), ("rows", "cols"), 5,
-                            dt=0.01, mode=mode)
+            got, dt = rk3_solve(u0, make_grid_mesh(*rc), ("rows", "cols"), 5,
+                                mode=mode)
             ok[f"{rc[0]}x{rc[1]}-{mode}"] = bool(
                 np.allclose(np.asarray(got), np.asarray(ref),
-                            rtol=2e-5, atol=2e-5))
+                            rtol=2e-5, atol=2e-5)
+                and np.allclose(np.asarray(dt), np.asarray(dt_ref),
+                                rtol=1e-6, atol=0))
     print(json.dumps(ok))
     """
     r = run_devices(code, 4)
