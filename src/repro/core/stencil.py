@@ -26,10 +26,10 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.domain import part_extents
-from repro.core.halo import (EXCHANGE, INTERIOR, REDUCE, UPDATE, _norm_subn,
-                             exchange_halo, halo_scan_nd, multi_dim_stencil,
-                             pad_with_halo, stencil_apply_nd,
-                             stencil_with_halo_nd)
+from repro.core.halo import (ASSEMBLE, EXCHANGE, FACES, INTERIOR, REDUCE,
+                             UPDATE, _norm_subn, exchange_halo, halo_scan_nd,
+                             multi_dim_stencil, pad_with_halo,
+                             stencil_apply_nd, stencil_with_halo_nd)
 from repro.core.reduction import hdot_reduce, task_reduce
 
 _STR_AXES_WARNED: set = set()
@@ -510,21 +510,19 @@ def rk3_solve(u0: jax.Array, mesh, mesh_axes, steps: int,
 
 
 # ============================================================ HPCCG CG (§4.3)
+def _window_sum(q: jax.Array, window: Tuple[int, ...], padding) -> jax.Array:
+    """Sum over each `window` box of `q`, zero-padded by `padding` (one
+    (lo, hi) pair per dim): a float32 window reduction on the vector unit."""
+    return lax.reduce_window(q, jnp.zeros((), q.dtype), lax.add, window,
+                             (1,) * q.ndim, padding)
+
+
 def _sum27(q: jax.Array) -> jax.Array:
     """HPCCG's 27-point operator (diag=26, off-diag=-1) on a fully padded
-    (nx+2, ny+2, nz+2) block; returns the (nx, ny, nz) interior."""
-    nx, ny, nz = q.shape[0] - 2, q.shape[1] - 2, q.shape[2] - 2
-    acc = 0.0
-    for dx in (-1, 0, 1):
-        for dy in (-1, 0, 1):
-            for dz in (-1, 0, 1):
-                sl = q[1 + dx:nx + 1 + dx, 1 + dy:ny + 1 + dy,
-                       1 + dz:nz + 1 + dz]
-                if dx == dy == dz == 0:
-                    acc = acc + 26.0 * sl
-                else:
-                    acc = acc - sl
-    return acc
+    (nx+2, ny+2, nz+2) block; returns the (nx, ny, nz) interior as
+    27·centre − W(q), W the 3×3×3 window sum."""
+    return (27.0 * q[1:-1, 1:-1, 1:-1]
+            - _window_sum(q, (3, 3, 3), ((0, 0),) * 3))
 
 
 def _stencil27_matvec(p: jax.Array, axis_name: Optional[str], mode: str,
@@ -557,20 +555,6 @@ def _stencil27_matvec(p: jax.Array, axis_name: Optional[str], mode: str,
                             subdomains=(subdomains,))
 
 
-def _chain_fn27(dims: Tuple[int, ...]):
-    """27-point apply for a block that ALREADY carries ghosts on every dim in
-    `dims` (plus width-1 padding on the last dim supplied by the caller);
-    the remaining dims are padded locally with zeros (global Dirichlet)."""
-    pads = tuple((0, 0) if d in dims else (1, 1) for d in range(3))
-
-    def fn(block: jax.Array) -> jax.Array:
-        if any(p != (0, 0) for p in pads):
-            block = jnp.pad(block, pads)
-        return _sum27(block)
-
-    return fn
-
-
 def _exchange_chain(p: jax.Array, axes: Tuple[str, ...],
                     dims: Tuple[int, ...]
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -581,34 +565,52 @@ def _exchange_chain(p: jax.Array, axes: Tuple[str, ...],
     the shared neighbors — then exchange the LAST dim's faces of the fully
     padded block. The final halo planes thus carry every (multi-)corner
     coupling of the 27-point operator with face ppermutes only: one pair per
-    axis, no corner messages. Returns (p_padded, lo_last, hi_last)."""
+    axis, no corner messages. Returns (p_padded, lo_last, hi_last), the last
+    two as 2-D planes: a trailing dim of 1 would lie on the 128 lanes."""
     with jax.named_scope(EXCHANGE):
         for a, d in zip(axes[:-1], dims[:-1]):
             p = pad_with_halo(p, a, 1, dim=d)
         lo, hi = exchange_halo(p, axes[-1], 1, dim=dims[-1], periodic=False)
-    return p, lo, hi
+    return p, lo[..., 0], hi[..., 0]
 
 
 def _stencil27_matvec_chain(p: jax.Array, axes: Tuple[str, ...],
                             dims: Tuple[int, ...], mode: str,
-                            halos=None, subdomains: int = 4) -> jax.Array:
+                            halos=None) -> jax.Array:
     """y = A p with block decomposition over the mesh dims in `dims` ((y, z)
-    or (x, y, z)). `halos` is the :func:`_exchange_chain` triple,
-    pre-exchanged by the pipelined CG; the interior chunk tasks along the
-    last dim read only the pre-padded block, so just the boundary-plane
-    tasks wait on the final ppermute pair."""
+    or (x, y, z); the last is z, dim 2). `halos` is the
+    :func:`_exchange_chain` triple, pre-exchanged by the pipelined CG.
+
+    hdot applies A p = 27·p − W(p) over the whole local block: the interior
+    task is W over the chain-padded block with zero z ghosts, so it reads no
+    z halo; each face task is the 3×3 window sum of one received z plane,
+    the only consumer of the last ppermute pair; the assemble adds each face
+    sum to the block's first or last z plane. The padded block's shells are
+    disjoint and the z planes carry every corner, so this is exact."""
     if halos is None:
         halos = _exchange_chain(p, axes, dims)
     p1, lo, hi = halos
-    fn = _chain_fn27(dims)
-    if mode == "hdot":
-        return stencil_with_halo_nd(p1, [(lo, hi)], fn, width=1,
-                                    dims=(dims[-1],),
-                                    subdomains=(subdomains,))
-    with jax.named_scope(EXCHANGE):
-        padded = jnp.concatenate([lo, p1, hi], axis=dims[-1])
+    # x, y ghosts no mesh axis carries are zeros (global Dirichlet)
+    pads = tuple((0, 0) if d in dims else (1, 1) for d in range(2))
+    if mode != "hdot":
+        with jax.named_scope(EXCHANGE):
+            padded = jnp.pad(jnp.concatenate([lo[..., None], p1,
+                                              hi[..., None]], axis=2),
+                             pads + ((0, 0),))
+        with jax.named_scope(INTERIOR):
+            return _sum27(padded)
     with jax.named_scope(INTERIOR):
-        return fn(padded)
+        box = _window_sum(p1, (3, 3, 3), pads + ((1, 1),))
+    with jax.named_scope(FACES):
+        f_lo, f_hi = (_window_sum(h, (3, 3), pads)[..., None]
+                      for h in (lo, hi))
+    with jax.named_scope(ASSEMBLE):
+        # a select on the z index, not a plane update: it fuses into the one
+        # pass over the block and leaves the layout of p to the compiler
+        z = lax.broadcasted_iota(jnp.int32, p.shape, 2)
+        edges = (jnp.where(z == 0, f_lo, 0.0)
+                 + jnp.where(z == p.shape[2] - 1, f_hi, 0.0))
+        return 27.0 * p - box - edges
 
 
 def _ddot(a: jax.Array, b: jax.Array, axis_name: Optional[str],
@@ -640,8 +642,7 @@ def _hpccg_solver(mesh, mesh_axes, iters: int, mode: str, subdomains: int):
 
     def matvec(p, halos):
         if chained:
-            return _stencil27_matvec_chain(p, axes, cdims, mode, halos=halos,
-                                           subdomains=subdomains)
+            return _stencil27_matvec_chain(p, axes, cdims, mode, halos=halos)
         return _stencil27_matvec(p, axis_name, mode, halos=halos,
                                  subdomains=subdomains)
 
@@ -718,10 +719,11 @@ def hpccg_solve(b: jax.Array, mesh, mesh_axes, iters: int,
 
     hdot mode pipelines the matvec halo: the exchange(s) for iteration k+1
     are launched the moment p_{k+1} is formed, so they ride behind the two
-    ddot allreduces, the waxpby tasks, and the next matvec's interior chunks
-    — only the boundary-plane tasks of the next matvec wait on them. The
-    jitted solver is cached per (mesh, topology, iters, mode, subdomains) so
-    repeated solves (and benchmark timings) pay compile once."""
+    ddot allreduces, the waxpby tasks, and the next matvec's interior task
+    (its interior chunks on slabs) — only the boundary-plane tasks of the
+    next matvec wait on them. The jitted solver is cached per (mesh,
+    topology, iters, mode, subdomains) so repeated solves (and benchmark
+    timings) pay compile once."""
     with jax.profiler.TraceAnnotation(SOLVE_SPAN):
         axes = normalize_mesh_axes(mesh_axes, "hpccg_solve", (1, 2, 3))
         return _hpccg_solver(mesh, axes, iters, mode, subdomains)(b)
