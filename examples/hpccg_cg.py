@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.stencil import _stencil27_matvec, hpccg_solve
+from repro.core.stencil import _sum27, hpccg_solve
 from repro.launch.mesh import make_mesh
 
 
@@ -26,8 +26,9 @@ def main() -> None:
         print(f"{mode:10s}: ||r|| {h[0]:.3e} -> {h[-1]:.3e} "
               f"({h[0]/h[-1]:.1e}x) in 40 iters")
 
-    # verify the solution actually solves the system
-    Ax = _stencil27_matvec(x, None, "hdot")
+    # verify the solution actually solves the system: A x on the zero-padded
+    # (Dirichlet) block
+    Ax = _sum27(jnp.pad(x, 1))
     rel = float(jnp.linalg.norm(Ax - b) / jnp.linalg.norm(b))
     print(f"relative residual ||Ax-b||/||b|| = {rel:.2e}")
     print("convergence is schedule-invariant; the schedules differ only in "

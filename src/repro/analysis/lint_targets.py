@@ -27,7 +27,7 @@ from typing import Callable, Dict, List, Tuple
 from repro.analysis.rules import LintContext
 
 # pair-count arithmetic per schedule (permute ops = 2 * pair-sets):
-#   halo_scan / heat2d : one fwd+bwd pair per axis per step, drain peeled
+#   halo_scan_nd / heat2d : one fwd+bwd pair per axis per step, drain peeled
 #   rk3                : 3 stages/step, fill + peeled final stage -> 3*steps
 #   hpccg              : one exchange chain per iter, fill + iters-1
 PERMUTES_HALO = lambda axes, steps: 2 * axes * steps
@@ -155,13 +155,13 @@ def _halo_target(name: str, ndim: int) -> Target:
 
 @target("halo1d")
 def _halo1d() -> Target:
-    """halo_scan, 1-D ring of 4, steps=2 unrolled+peeled, donated input."""
+    """halo_scan_nd, 1-D ring of 4, steps=2 unrolled+peeled, donated input."""
     return _halo_target("halo1d", 1)
 
 
 @target("halo2d")
 def _halo2d() -> Target:
-    """halo_scan_2d on a 2x2 mesh, steps=2 unrolled+peeled, donated input."""
+    """halo_scan_nd on a 2x2 mesh, steps=2 unrolled+peeled, donated input."""
     return _halo_target("halo2d", 2)
 
 

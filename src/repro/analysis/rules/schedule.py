@@ -28,13 +28,13 @@ class DeadDrainRule(Rule):
     """A collective-permute whose result never reaches compute or the program
     output is a dead drain exchange: pure wire traffic with no consumer.
 
-    This is exactly the PR-3 regression: an unpeeled halo_scan issues the
-    step-N exchange whose halos no step ever reads. Detected by tuple-aware
+    An unpeeled halo_scan_nd makes exactly this: it issues the step-N
+    exchange whose halos no step ever reads. Detected by tuple-aware
     interprocedural taint from each ppermute result.
     """
     id = "DEAD-DRAIN"
     fix_hint = ("peel the final exchange out of the steady-state loop "
-                "(halo_scan(..., peel=True)) so the drain step computes "
+                "(halo_scan_nd(..., peel=True)) so the drain step computes "
                 "without communicating")
 
     def check(self, module: HloModule, ctx: LintContext) -> List[Finding]:

@@ -1,17 +1,18 @@
-"""Double-buffered all-to-all: the `halo_scan` schedule applied to a2a.
+"""Double-buffered all-to-all: the `halo_scan_nd` schedule applied to a2a.
 
 An expert-parallel MoE layer moves every routed token twice through a single
 monolithic ``all_to_all`` pair (dispatch there, combine back) — the one
 collective that dominates large-MoE step time, and in the monolithic form the
 exact "bulk communication with zero overlap window" shape the HDOT paper
-taskifies away. `a2a_scan` applies the same move as `core.halo.halo_scan`:
-over-decompose the transfer along one dim into ``chunks`` slices and schedule
+taskifies away. `a2a_scan` applies the same move as
+`core.halo.halo_scan_nd`: over-decompose the transfer along one dim into
+``chunks`` slices and schedule
 
     dispatch a2a(k+1)  ||  compute(k)  ||  combine a2a(k-1)
 
 so every slice's wire time sits inside a neighbor slice's compute. The
 prologue (first dispatch) and drain (last combine) are peeled exactly like
-halo_scan's first/last exchange.
+halo_scan_nd's first/last exchange.
 
 Trace order per step k (prologue ``dispatch(0)`` already issued):
 

@@ -45,18 +45,11 @@ so a measured-cost re-partition produces UNEVEN interior chunk grids while
 the onion face partition (and thus the ppermute schedule) is untouched: the
 faces depend only on `width`, never on where the interior is cut.
 
-The 1-D (``halo_scan``/``stencil_hdot``/...) and 2-D (``*_2d``) entry points
-are DEPRECATED thin aliases of the N-D implementation, kept for their
-ergonomic signatures (explicit ``lo/hi`` halos in 1-D; the flat four-halo
-tuple in 2-D); new code should spell the decomposition once, as
-``axes=((axis_name, dim), ...)``.
-
 All functions run inside ``shard_map`` bodies; `axis_name` names the mesh axis
 that carries the process-level domain decomposition for `dim`.
 """
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -68,7 +61,6 @@ from repro.core.domain import interior_boxes
 
 # One decomposed dim: (mesh_axis_name, array_dim).
 Axes = Sequence[Tuple[str, int]]
-Decomp = Axes  # deprecated alias, pre-unification spelling
 
 # The HDOT stages, as `jax.named_scope` names: every op of a solver is traced
 # under at most one of them (the innermost wins), so a device trace's op
@@ -80,19 +72,6 @@ ASSEMBLE = "hdot.assemble"    # concatenates of chunk and face outputs
 EXCHANGE = "hdot.exchange"    # halo slices, ppermutes, pads and stitching
 REDUCE = "hdot.reduce"        # residuals, dot products, their allreduces
 UPDATE = "hdot.update"        # the solver's vector updates and scalars
-
-_DEPRECATION_WARNED: set = set()
-
-
-def _warn_deprecated(name: str, repl: str) -> None:
-    """Once-per-process deprecation note for the pre-N-D entry points."""
-    if name in _DEPRECATION_WARNED:
-        return
-    _DEPRECATION_WARNED.add(name)
-    warnings.warn(
-        f"{name} is a deprecated alias; use {repl} with "
-        f"axes=((axis_name, dim), ...)", DeprecationWarning, stacklevel=3)
-
 
 def _edge(u: jax.Array, dim: int, side: str, width: int) -> jax.Array:
     n = u.shape[dim]
@@ -186,10 +165,6 @@ def _norm_subn(subdomains, n: int) -> Tuple[int, ...]:
             f"decomposition is {n}-dimensional; pass an int or one chunk "
             f"count per dim")
     return t
-
-
-def _norm_sub2(subdomains) -> Tuple[int, int]:
-    return _norm_subn(subdomains, 2)
 
 
 def exchange_halo_nd(u: jax.Array, axes: Axes, width: int,
@@ -579,165 +554,6 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
     if partial_fn is None:
         return a, None
     return a, jnp.concatenate(outs)
-
-
-# --------------------------------------------------------------------------
-# 1-D entry points — DEPRECATED thin aliases of the N-D core, kept for the
-# explicit (lo_halo, hi_halo) signatures older callers use. New code spells
-# the decomposition as axes=((axis_name, dim),). `stencil_fn(padded)`
-# consumes a block padded by `width` on both ends of `dim` only.
-# --------------------------------------------------------------------------
-
-def stencil_two_phase(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-                      axis_name: str, width: int, dim: int,
-                      periodic: bool = False) -> jax.Array:
-    """Deprecated alias: comm(D); barrier; compute(D) — paper Code 2."""
-    _warn_deprecated("stencil_two_phase", "stencil_two_phase_nd")
-    return stencil_two_phase_nd(u, stencil_fn, ((axis_name, dim),), width,
-                                periodic)
-
-
-def stencil_with_halo(u: jax.Array, lo_halo: jax.Array, hi_halo: jax.Array,
-                      stencil_fn: Callable[[jax.Array], jax.Array],
-                      width: int, dim: int, subdomains: int = 4) -> jax.Array:
-    """Deprecated alias of :func:`stencil_with_halo_nd` (halos spelled as the
-    flat (lo, hi) pair): apply `stencil_fn` to a block whose halos were
-    ALREADY received. Boundary strips consume the halos; the interior is
-    over-decomposed into `subdomains` chunks."""
-    _warn_deprecated("stencil_with_halo", "stencil_with_halo_nd")
-    return stencil_with_halo_nd(u, [(lo_halo, hi_halo)], stencil_fn, width,
-                                (dim,), (subdomains,))
-
-
-def stencil_hdot(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-                 axis_name: str, width: int, dim: int,
-                 periodic: bool = False,
-                 subdomains: int = 4) -> jax.Array:
-    """Deprecated alias of :func:`stencil_hdot_nd`, one mesh axis."""
-    _warn_deprecated("stencil_hdot", "stencil_hdot_nd")
-    return stencil_hdot_nd(u, stencil_fn, ((axis_name, dim),), width,
-                           periodic, (subdomains,))
-
-
-def stencil_apply(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-                  axis_name: str, width: int, dim: int,
-                  periodic: bool = False, mode: str = "hdot",
-                  subdomains: int = 4) -> jax.Array:
-    """Deprecated alias of :func:`stencil_apply_nd`, one mesh axis."""
-    _warn_deprecated("stencil_apply", "stencil_apply_nd")
-    return stencil_apply_nd(u, stencil_fn, ((axis_name, dim),), width,
-                            periodic, mode, (subdomains,))
-
-
-def halo_scan(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-              axis_name: str, width: int, dim: int, steps: int,
-              periodic: bool = False, mode: str = "hdot",
-              subdomains: int = 4,
-              partial_fn: Optional[Callable[[jax.Array, jax.Array], jax.Array]]
-              = None, unroll: int = 1,
-              peel: bool = True) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Deprecated alias: double-buffered multi-step driver on one mesh axis
-    (see :func:`halo_scan_nd` for the schedule)."""
-    _warn_deprecated("halo_scan", "halo_scan_nd")
-    return halo_scan_nd(u, stencil_fn, ((axis_name, dim),), width, steps,
-                        periodic, mode, (subdomains,), partial_fn, unroll,
-                        peel)
-
-
-# --------------------------------------------------------------------------
-# 2-D (rows x cols) entry points — DEPRECATED thin aliases of the N-D core,
-# kept for the flat four-halo tuple signature. New code spells the
-# decomposition as axes=((row_axis, dim0), (col_axis, dim1)).
-# `stencil_fn(padded)` consumes a block padded by `width` on both ends of
-# BOTH dims in `dims`.
-# --------------------------------------------------------------------------
-
-def _halos2(halos):
-    lo0, hi0, lo1, hi1 = halos
-    return ((lo0, hi0), (lo1, hi1))
-
-
-def exchange_halo_2d(u: jax.Array, axis_names: Tuple[str, str], width: int,
-                     dims: Tuple[int, int], periodic: bool = False
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Deprecated alias: combined edge exchange on both mesh axes (one
-    ppermute pair per axis). Returns (lo0, hi0, lo1, hi1); corner ghosts are
-    NOT exchanged."""
-    _warn_deprecated("exchange_halo_2d", "exchange_halo_nd")
-    (lo0, hi0), (lo1, hi1) = exchange_halo_nd(
-        u, tuple(zip(axis_names, dims)), width, periodic)
-    return lo0, hi0, lo1, hi1
-
-
-def pad_with_halo_2d(u: jax.Array, halos, width: int, dims: Tuple[int, int]
-                     ) -> jax.Array:
-    """Deprecated alias: assemble the corner-free padded block — halos on the
-    four faces, ZEROS in the (2*width)^2 corners (star stencils never read
-    them)."""
-    _warn_deprecated("pad_with_halo_2d", "pad_with_halo_nd")
-    return pad_with_halo_nd(u, _halos2(halos), width, dims)
-
-
-def stencil_two_phase_2d(u: jax.Array,
-                         stencil_fn: Callable[[jax.Array], jax.Array],
-                         axis_names: Tuple[str, str], width: int,
-                         dims: Tuple[int, int], periodic: bool = False
-                         ) -> jax.Array:
-    """Deprecated alias: comm(both axes); barrier; compute(whole block)."""
-    _warn_deprecated("stencil_two_phase_2d", "stencil_two_phase_nd")
-    return stencil_two_phase_nd(u, stencil_fn, tuple(zip(axis_names, dims)),
-                                width, periodic)
-
-
-def stencil_with_halo_2d(u: jax.Array, halos,
-                         stencil_fn: Callable[[jax.Array], jax.Array],
-                         width: int, dims: Tuple[int, int],
-                         subdomains=(2, 2)) -> jax.Array:
-    """Deprecated alias of :func:`stencil_with_halo_nd` (halos spelled as the
-    flat four-tuple): apply `stencil_fn` to a block whose four face halos
-    were ALREADY received."""
-    _warn_deprecated("stencil_with_halo_2d", "stencil_with_halo_nd")
-    return stencil_with_halo_nd(u, _halos2(halos), stencil_fn, width, dims,
-                                _norm_sub2(subdomains))
-
-
-def stencil_hdot_2d(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-                    axis_names: Tuple[str, str], width: int,
-                    dims: Tuple[int, int], periodic: bool = False,
-                    subdomains=(2, 2)) -> jax.Array:
-    """Deprecated alias of :func:`stencil_hdot_nd`: four strip tasks consume
-    the two ppermute pairs; the (kr x kc) interior grid depends only on u."""
-    _warn_deprecated("stencil_hdot_2d", "stencil_hdot_nd")
-    return stencil_hdot_nd(u, stencil_fn, tuple(zip(axis_names, dims)), width,
-                           periodic, _norm_sub2(subdomains))
-
-
-def stencil_apply_2d(u: jax.Array,
-                     stencil_fn: Callable[[jax.Array], jax.Array],
-                     axis_names: Tuple[str, str], width: int,
-                     dims: Tuple[int, int], periodic: bool = False,
-                     mode: str = "hdot", subdomains=(2, 2)) -> jax.Array:
-    """Deprecated alias of :func:`stencil_apply_nd`, two mesh axes."""
-    _warn_deprecated("stencil_apply_2d", "stencil_apply_nd")
-    return stencil_apply_nd(u, stencil_fn, tuple(zip(axis_names, dims)),
-                            width, periodic, mode, _norm_sub2(subdomains))
-
-
-def halo_scan_2d(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
-                 axis_names: Tuple[str, str], width: int,
-                 dims: Tuple[int, int], steps: int, periodic: bool = False,
-                 mode: str = "hdot", subdomains=(2, 2),
-                 partial_fn: Optional[Callable[[jax.Array, jax.Array],
-                                               jax.Array]] = None,
-                 unroll: int = 1, peel: bool = True
-                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Deprecated alias: double-buffered multi-step driver on a (rows x cols)
-    mesh (see :func:`halo_scan_nd` for the schedule; both axes' exchanges
-    ride behind the interior compute, and the drain step is peeled)."""
-    _warn_deprecated("halo_scan_2d", "halo_scan_nd")
-    return halo_scan_nd(u, stencil_fn, tuple(zip(axis_names, dims)), width,
-                        steps, periodic, mode, _norm_sub2(subdomains),
-                        partial_fn, unroll, peel)
 
 
 def multi_dim_stencil(u: jax.Array,

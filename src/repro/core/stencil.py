@@ -9,16 +9,18 @@ schedules are numerically identical.
 
 All solvers are shard_map'd over the process-level decomposition — one mesh
 axis (the paper's slabs), a 2-D (rows x cols) grid mesh, or (HPCCG) a full
-3-D (x, y, z) mesh — and over-decompose each shard into task-level
-subdomains (``subdomains=`` — the paper's grainsize knob) for residual
-reductions and boundary/interior splits.
+3-D (x, y, z) mesh. Each solver has one decomposition path: a slab is the
+grid path with one axis. Each shard is over-decomposed into task-level
+subdomains: Heat2D's interior chunk grid (``subdomains=``), CREAMS's
+per-direction chunks (``EULER_SUBDOMAINS``) and HPCCG's dot-product
+partials (``subdomains=``).
 """
 from __future__ import annotations
 
 import functools
 import math
-import warnings
-from typing import Optional, Tuple
+import operator
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +29,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.domain import part_extents
 from repro.core.halo import (ASSEMBLE, EXCHANGE, FACES, INTERIOR, REDUCE,
-                             UPDATE, _norm_subn, exchange_halo, halo_scan_nd,
+                             UPDATE, _norm_subn, exchange_halo,
+                             exchange_halo_nd, halo_scan_nd,
                              multi_dim_stencil, pad_with_halo,
-                             stencil_apply_nd, stencil_with_halo_nd)
+                             stencil_with_halo_nd)
 from repro.core.reduction import hdot_reduce, task_reduce
-
-_STR_AXES_WARNED: set = set()
 
 # Host span around a solver entry (cut computation, solver lookup, dispatch),
 # on the profiler's clock: a recompile or host stall there shows by name.
@@ -44,24 +45,13 @@ def normalize_mesh_axes(mesh_axes, solver: str,
     """THE solver mesh-topology contract: every solver takes
     ``mesh_axes: tuple[str, ...]`` — one mesh axis name per decomposed grid
     dim, arity selecting the topology (1 = the paper's slabs, 2/3 = grid
-    meshes). A bare string is accepted as a deprecated 1-axis spelling and
-    coerced (with a once-per-process note); anything else out of contract
-    raises a ValueError naming the solver and its accepted arities."""
-    if isinstance(mesh_axes, str):
-        if solver not in _STR_AXES_WARNED:
-            _STR_AXES_WARNED.add(solver)
-            warnings.warn(
-                f"{solver}: passing mesh_axes as a bare axis name is "
-                f"deprecated; pass a tuple, e.g. ({mesh_axes!r},)",
-                DeprecationWarning, stacklevel=3)
-        axes = (mesh_axes,)
-    else:
-        try:
-            axes = tuple(mesh_axes)
-        except TypeError:
-            raise ValueError(
-                f"{solver}: mesh_axes must be a tuple of mesh axis names, "
-                f"got {mesh_axes!r}") from None
+    meshes). Anything out of contract, a bare string included, raises a
+    ValueError naming the solver and its accepted arities."""
+    if isinstance(mesh_axes, str) or not hasattr(mesh_axes, "__iter__"):
+        raise ValueError(
+            f"{solver}: mesh_axes must be a tuple of mesh axis names, "
+            f"got {mesh_axes!r}")
+    axes = tuple(mesh_axes)
     if not all(isinstance(a, str) for a in axes):
         raise ValueError(
             f"{solver}: mesh_axes entries must be mesh axis names (str), "
@@ -77,17 +67,10 @@ def normalize_mesh_axes(mesh_axes, solver: str,
 
 
 # =============================================================== Heat2D (§4.1)
-def _jacobi_stencil(padded: jax.Array, dim: int = 0) -> jax.Array:
-    """5-point Jacobi update. `padded` has 1 ghost row on both ends of dim 0;
-    dim 1 uses Dirichlet-0 global boundaries (zero pad)."""
-    assert dim == 0
-    p = jnp.pad(padded, ((0, 0), (1, 1)))
-    return 0.25 * (p[:-2, 1:-1] + p[2:, 1:-1] + p[1:-1, :-2] + p[1:-1, 2:])
-
-
 def _jacobi_stencil_2d(padded: jax.Array) -> jax.Array:
-    """5-point Jacobi on a block padded by 1 ghost cell on BOTH dims (the
-    2-D-mesh contract; corner ghosts are dead — the star never reads them)."""
+    """5-point Jacobi on a block padded by 1 ghost cell on BOTH dims (corner
+    ghosts are dead — the star never reads them). Slabs zero-pad dim 1
+    first: its Dirichlet-0 edges."""
     return 0.25 * (padded[:-2, 1:-1] + padded[2:, 1:-1]
                    + padded[1:-1, :-2] + padded[1:-1, 2:])
 
@@ -110,7 +93,10 @@ def _heat2d_solver(mesh, axes, iters: int, mode: str, subdomains, cuts=None):
     axes = normalize_mesh_axes(axes, "heat2d_solve", (1, 2))
     subs = _norm_subn(subdomains, len(axes))
     hs_axes = tuple((a, d) for d, a in enumerate(axes))
-    stencil_fn = _jacobi_stencil_2d if len(axes) == 2 else _jacobi_stencil
+
+    # slabs: dim 1 is whole, its Dirichlet-0 ghosts a local zero pad
+    stencil_fn = (_jacobi_stencil_2d if len(axes) == 2 else
+                  lambda q: _jacobi_stencil_2d(jnp.pad(q, ((0, 0), (1, 1)))))
 
     def local(u):
         return halo_scan_nd(
@@ -118,7 +104,7 @@ def _heat2d_solver(mesh, axes, iters: int, mode: str, subdomains, cuts=None):
             mode=mode, subdomains=subs, partial_fn=_abs_change,
             weights=cuts)
 
-    spec = P(*axes) if len(axes) == 2 else P(axes[0], None)
+    spec = P(*axes)
     f = jax.shard_map(local, mesh=mesh, in_specs=spec,
                       out_specs=(spec, P()))
     return jax.jit(f)
@@ -213,6 +199,9 @@ def heat2d_init(nx: int, ny: int, dtype=jnp.float32) -> jax.Array:
 # (Jiang & Shu, J. Comput. Phys. 126, 1996; Shu, ICASE Report 97-65), whose
 # stencil reaches 3 cells past a cell on each side: the halo width.
 EULER_WIDTH = 3
+# Task-level subdomains: interior chunks per decomposed direction, and the
+# CFL max's partials over z.
+EULER_SUBDOMAINS = 4
 _WENO_EPS = 1e-6
 GAMMA = 1.4           # ratio of specific heats: one calorically perfect gas
 CFL = 0.5             # dt = CFL / max over cells of sum_d (|u_d| + c) / dx_d
@@ -278,14 +267,15 @@ def euler_llf(padded: jax.Array, dim: int,
             - lax.slice_in_dim(face, 1, n + 1, axis=dim)) * inv_dx[dim - 1]
 
 
-def _cfl_dt(u: jax.Array, inv_dx, axes, subdomains: int = 4) -> jax.Array:
+def _cfl_dt(u: jax.Array, inv_dx, axes) -> jax.Array:
     """dt = CFL / max over cells of sum_d (|u_d| + c) / dx_d, from the state
     at a step's start: each task's partial max over its slab of z, then the
     max over the tasks and the mesh (paper Code 5). No stage of the step can
     update before it."""
     with jax.named_scope(REDUCE):
         parts = []
-        for blk in jnp.array_split(u, min(subdomains, u.shape[3]), axis=3):
+        for blk in jnp.array_split(u, min(EULER_SUBDOMAINS, u.shape[3]),
+                                   axis=3):
             vel, _, c = _primitives(blk)
             rate = (jnp.abs(vel[0]) + c) * inv_dx[0]
             for d in (1, 2):
@@ -306,110 +296,76 @@ def _rk3_stage(u, s, rhs, dt, a: float, b: float):
         return u + b * s, s
 
 
-def _euler_rhs(u: jax.Array, axis_name, mode: str, inv_dx) -> jax.Array:
+def _directions(axes: Tuple[str, ...]) -> Tuple[Tuple[str, int], ...]:
+    """The decomposed directions: the mesh axes carry the trailing array
+    dims, z for one axis, (y, z) for two, as ``(axis, dim)`` pairs."""
+    return tuple(zip(axes, range(4 - len(axes), 4)))
+
+
+def _euler_rhs(u: jax.Array, axes: Tuple[str, ...], mode: str,
+               inv_dx) -> jax.Array:
     """The three flux tasks (paper Figure 5), each stage's halos exchanged
-    inside the stage. `axis_name` is one mesh axis (z decomposed) or a
-    (y_axis, z_axis) pair; each direction's task needs only its OWN axis's
-    halo (direction-split fluxes have no cross-dim couplings), so a 2-D mesh
+    inside the stage. Each direction's task needs only its OWN axis's halo
+    (direction-split fluxes have no cross-dim couplings), so a 2-D mesh
     needs no corner messages."""
-    if isinstance(axis_name, tuple):
-        ay, az = axis_name
-        decomp = [(1, None), (2, ay), (3, az)]
-    else:
-        decomp = [(1, None), (2, None), (3, axis_name)]
-    return multi_dim_stencil(u, _llf_task(inv_dx), decomp,
+    carried = {d: a for a, d in _directions(axes)}
+    return multi_dim_stencil(u, _llf_task(inv_dx),
+                             [(d, carried.get(d)) for d in (1, 2, 3)],
                              width=EULER_WIDTH, periodic=True, mode=mode)
 
 
-def _euler_rhs_with_halo(u: jax.Array, lo: jax.Array, hi: jax.Array, inv_dx,
-                         subdomains: int = 4) -> jax.Array:
-    """RHS with z-halos already in hand (pipelined schedule): the x/y tasks
-    pad locally, the z task's faces consume the carried halos — no exchange
-    on this stage's critical path."""
-    xy = multi_dim_stencil(u, _llf_task(inv_dx),
-                           [(1, None), (2, None)], width=EULER_WIDTH,
-                           periodic=True)
-    z = stencil_with_halo_nd(u, [(lo, hi)], _llf_task(inv_dx, 3),
-                             width=EULER_WIDTH, dims=(3,),
-                             subdomains=(subdomains,))
+def _euler_rhs_carried(u: jax.Array, halos, dirs, inv_dx) -> jax.Array:
+    """RHS with every decomposed direction's halos already in hand (one
+    (lo, hi) pair per entry of `dirs`): a direction no mesh axis carries
+    pads locally; each decomposed direction's faces consume its own carried
+    pair, so no exchange sits on this stage's critical path, and the
+    per-direction interior chunks are the independent work the ppermute
+    pairs hide behind. The tasks are all formed, then summed x, y, z."""
+    carried = {d: h for (_, d), h in zip(dirs, halos)}
+    outs = []
+    for d in (1, 2, 3):
+        if d in carried:
+            outs.append(stencil_with_halo_nd(
+                u, [carried[d]], _llf_task(inv_dx, d), width=EULER_WIDTH,
+                dims=(d,), subdomains=(EULER_SUBDOMAINS,)))
+        else:
+            outs.append(multi_dim_stencil(u, _llf_task(inv_dx), [(d, None)],
+                                          width=EULER_WIDTH, periodic=True))
     with jax.named_scope(UPDATE):
-        return xy + z
+        return functools.reduce(operator.add, outs)
 
 
-def _euler_rhs_with_halo_2d(u: jax.Array, hy, hz, inv_dx,
-                            subdomains: int = 4) -> jax.Array:
-    """RHS with BOTH mesh axes' halos already in hand ((y, z) grid mesh):
-    the x task pads locally; the y and z tasks' faces each consume their own
-    carried halo pair — neither exchange sits on this stage's critical path,
-    and the per-direction interior chunks are the independent work both
-    ppermute pairs hide behind."""
-    x = multi_dim_stencil(u, _llf_task(inv_dx), [(1, None)],
-                          width=EULER_WIDTH, periodic=True)
-    y = stencil_with_halo_nd(u, [hy], _llf_task(inv_dx, 2),
-                             width=EULER_WIDTH, dims=(2,),
-                             subdomains=(subdomains,))
-    z = stencil_with_halo_nd(u, [hz], _llf_task(inv_dx, 3),
-                             width=EULER_WIDTH, dims=(3,),
-                             subdomains=(subdomains,))
-    with jax.named_scope(UPDATE):
-        return x + y + z
-
-
-def rk3_local_step(u: jax.Array, axis_name, mode: str,
+def rk3_local_step(u: jax.Array, axes: Tuple[str, ...], mode: str,
                    inv_dx) -> Tuple[jax.Array, jax.Array]:
     """One 3-stage low-storage RK step (paper Code 8's rk loop): the CFL dt
     from the step's state, then per stage data-prep -> halo comm ->
     per-direction flux tasks -> update. Returns (U, dt)."""
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
     dt = _cfl_dt(u, inv_dx, axes)
     s = None
     for a, b in zip(_RK3_A, _RK3_B):
-        u, s = _rk3_stage(u, s, _euler_rhs(u, axis_name, mode, inv_dx), dt,
-                          a, b)
+        u, s = _rk3_stage(u, s, _euler_rhs(u, axes, mode, inv_dx), dt, a, b)
     return u, dt
 
 
-def rk3_local_step_pipelined(u: jax.Array, lo: jax.Array, hi: jax.Array,
-                             axis_name: str, inv_dx, subdomains: int = 4,
-                             exchange_last: bool = True):
-    """RK3 step with z-halos carried across stages: each stage consumes the
-    halos exchanged at the END of the previous stage, and launches the next
-    exchange the moment its U update lands — so every z ppermute pair (all
-    five components in one pair) flies behind the next stage's x/y tasks and
-    interior z chunks (the double-buffered analogue of Code 8's comm task).
-    `exchange_last=False` peels the drain: the solve's final stage feeds no
-    consumer, so its exchange would be a dead ppermute pair. Returns
-    (U, lo, hi, dt)."""
-    dt = _cfl_dt(u, inv_dx, (axis_name,), subdomains)
-    s = None
-    for i, (a, b) in enumerate(zip(_RK3_A, _RK3_B)):
-        rhs = _euler_rhs_with_halo(u, lo, hi, inv_dx, subdomains)
-        u, s = _rk3_stage(u, s, rhs, dt, a, b)
-        if exchange_last or i < len(_RK3_A) - 1:
-            lo, hi = exchange_halo(u, axis_name, EULER_WIDTH, dim=3,
-                                   periodic=True)
-    return u, lo, hi, dt
-
-
-def rk3_local_step_pipelined_2d(u: jax.Array, hy, hz, ay: str, az: str,
-                                inv_dx, subdomains: int = 4,
-                                exchange_last: bool = True):
-    """RK3 step on a (y, z) grid mesh with BOTH axes' halos carried across
+def rk3_local_step_carried(u: jax.Array, halos, dirs, inv_dx,
+                           exchange_last: bool = True):
+    """RK3 step with every decomposed direction's halos carried across
     stages: each stage consumes the pairs exchanged at the END of the
-    previous stage and launches the next y AND z exchanges the moment its
-    U update lands — so every ppermute pair flies behind the next stage's
-    x task and the y/z interior chunks. `exchange_last=False` peels the
-    drain (the solve's final stage feeds no consumer — two dead pairs saved
-    per solve). Returns (U, hy, hz, dt)."""
-    dt = _cfl_dt(u, inv_dx, (ay, az), subdomains)
+    previous stage and launches the next exchanges, in `dirs` order, the
+    moment its U update lands, so every ppermute pair (all five components
+    in one pair) flies behind the next stage's local tasks and interior
+    chunks (the double-buffered analogue of Code 8's comm task).
+    `exchange_last=False` peels the drain: the solve's final stage feeds no
+    consumer, so its exchanges would be dead ppermute pairs. Returns
+    (U, halos, dt)."""
+    dt = _cfl_dt(u, inv_dx, tuple(a for a, _ in dirs))
     s = None
     for i, (a, b) in enumerate(zip(_RK3_A, _RK3_B)):
-        rhs = _euler_rhs_with_halo_2d(u, hy, hz, inv_dx, subdomains)
+        rhs = _euler_rhs_carried(u, halos, dirs, inv_dx)
         u, s = _rk3_stage(u, s, rhs, dt, a, b)
         if exchange_last or i < len(_RK3_A) - 1:
-            hy = exchange_halo(u, ay, EULER_WIDTH, dim=2, periodic=True)
-            hz = exchange_halo(u, az, EULER_WIDTH, dim=3, periodic=True)
-    return u, hy, hz, dt
+            halos = exchange_halo_nd(u, dirs, EULER_WIDTH, periodic=True)
+    return u, halos, dt
 
 
 def euler_tgv_init(shape) -> jax.Array:
@@ -433,51 +389,31 @@ def euler_tgv_init(shape) -> jax.Array:
 def _rk3_solver(mesh, axes, steps: int, inv_dx: Tuple[float, ...],
                 mode: str):
     axes = normalize_mesh_axes(axes, "rk3_solve", (1, 2))
-    two_d = len(axes) == 2
-    ay, az = axes if two_d else (None, None)
-    axis_name = axes if two_d else axes[0]
+    dirs = _directions(axes)
     w = EULER_WIDTH
 
     def local(u):
-        if (two_d and mode == "hdot" and u.shape[2] >= 4 * w
-                and u.shape[3] >= 4 * w and steps > 0):
-            hy = exchange_halo(u, ay, w, dim=2, periodic=True)
-            hz = exchange_halo(u, az, w, dim=3, periodic=True)
+        if (mode == "hdot" and all(u.shape[d] >= 4 * w for _, d in dirs)
+                and steps > 0):
+            halos = exchange_halo_nd(u, dirs, w, periodic=True)  # fill
 
             def body(carry, _):
-                u, hy, hz, dt = rk3_local_step_pipelined_2d(*carry, ay, az,
-                                                            inv_dx)
-                return (u, hy, hz), dt
+                u, halos, dt = rk3_local_step_carried(*carry, dirs, inv_dx)
+                return (u, halos), dt
 
             # drain peeled: the last step's last-stage exchanges are dead
-            (u, hy, hz), dts = lax.scan(body, (u, hy, hz), None,
-                                        length=steps - 1)
-            u, _, _, dt = rk3_local_step_pipelined_2d(u, hy, hz, ay, az,
-                                                      inv_dx,
-                                                      exchange_last=False)
+            (u, halos), dts = lax.scan(body, (u, halos), None,
+                                       length=steps - 1)
+            u, _, dt = rk3_local_step_carried(u, halos, dirs, inv_dx,
+                                              exchange_last=False)
             return u, jnp.concatenate([dts, dt[None]])
 
-        if not two_d and mode == "hdot" and u.shape[3] >= 4 * w and steps > 0:
-            lo, hi = exchange_halo(u, axis_name, w, dim=3,
-                                   periodic=True)  # pipeline fill
-
-            def body(carry, _):
-                u, lo, hi, dt = rk3_local_step_pipelined(*carry, axis_name,
-                                                         inv_dx)
-                return (u, lo, hi), dt
-
-            # drain peeled: the last step's last-stage exchange is dead
-            (u, lo, hi), dts = lax.scan(body, (u, lo, hi), None,
-                                        length=steps - 1)
-            u, _, _, dt = rk3_local_step_pipelined(u, lo, hi, axis_name,
-                                                   inv_dx, exchange_last=False)
-            return u, jnp.concatenate([dts, dt[None]])
-
+        # two-phase, or blocks too small for hdot: halos inside each stage
         def body(u, _):
-            return rk3_local_step(u, axis_name, mode, inv_dx)
+            return rk3_local_step(u, axes, mode, inv_dx)
         return lax.scan(body, u, None, length=steps)
 
-    spec = P(None, None, ay, az) if two_d else P(None, None, None, axis_name)
+    spec = P(*((None,) * (4 - len(axes)) + axes))
     f = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=(spec, P()))
     return jax.jit(f)
 
@@ -496,10 +432,11 @@ def rk3_solve(u0: jax.Array, mesh, mesh_axes, steps: int,
 
     `mesh_axes` is the unified solver topology contract: ``(z_axis,)`` — the
     paper's z-decomposed slabs — or a ``(y_axis, z_axis)`` pair — true 2-D
-    (y, z) grid-mesh decomposition with stage-carried halos on BOTH axes
-    (each direction-split task consumes only its own axis's pair, so the 2-D
-    mesh needs no corner messages). One ppermute pair per axis per stage
-    carries all five components."""
+    (y, z) grid-mesh decomposition. Both run one path: hdot carries each
+    decomposed direction's halos across stages (each direction-split task
+    consumes only its own axis's pair, so the 2-D mesh needs no corner
+    messages). One ppermute pair per axis per stage carries all five
+    components."""
     with jax.profiler.TraceAnnotation(SOLVE_SPAN):
         axes = normalize_mesh_axes(mesh_axes, "rk3_solve", (1, 2))
         if u0.ndim != 4 or u0.shape[0] != 5:
@@ -525,36 +462,6 @@ def _sum27(q: jax.Array) -> jax.Array:
             - _window_sum(q, (3, 3, 3), ((0, 0),) * 3))
 
 
-def _stencil27_matvec(p: jax.Array, axis_name: Optional[str], mode: str,
-                      halos: Optional[Tuple[jax.Array, jax.Array]] = None,
-                      subdomains: int = 4) -> jax.Array:
-    """y = A p for the 27-point operator on a 3-D grid stacked along z
-    (dim 2), halo width 1. Only z is decomposed, so the exchanged plane
-    carries all in-plane diagonals (corner-free exchange).
-
-    `halos=(lo, hi)` supplies pre-exchanged z-planes (the pipelined CG
-    schedule: the exchange for iteration k+1's matvec departs when p_{k+1} is
-    formed, and only the boundary-plane tasks here consume it)."""
-
-    def per_z(padded: jax.Array, dim: int) -> jax.Array:
-        assert dim == 2
-        # pad x,y locally with zeros (global Dirichlet)
-        return _sum27(jnp.pad(padded, ((1, 1), (1, 1), (0, 0))))
-
-    fn = functools.partial(per_z, dim=2)
-    if halos is not None:
-        return stencil_with_halo_nd(p, [halos], fn, width=1, dims=(2,),
-                                    subdomains=(subdomains,))
-    if axis_name is None:
-        with jax.named_scope(EXCHANGE):
-            padded = jnp.pad(p, [(0, 0), (0, 0), (1, 1)])
-        with jax.named_scope(INTERIOR):
-            return fn(padded)
-    return stencil_apply_nd(p, fn, ((axis_name, 2),), width=1,
-                            periodic=False, mode=mode,
-                            subdomains=(subdomains,))
-
-
 def _exchange_chain(p: jax.Array, axes: Tuple[str, ...],
                     dims: Tuple[int, ...]
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -577,8 +484,8 @@ def _exchange_chain(p: jax.Array, axes: Tuple[str, ...],
 def _stencil27_matvec_chain(p: jax.Array, axes: Tuple[str, ...],
                             dims: Tuple[int, ...], mode: str,
                             halos=None) -> jax.Array:
-    """y = A p with block decomposition over the mesh dims in `dims` ((y, z)
-    or (x, y, z); the last is z, dim 2). `halos` is the
+    """y = A p with block decomposition over the mesh dims in `dims` (z,
+    (y, z) or (x, y, z); the last is z, dim 2). `halos` is the
     :func:`_exchange_chain` triple, pre-exchanged by the pipelined CG.
 
     hdot applies A p = 27·p − W(p) over the whole local block: the interior
@@ -613,59 +520,41 @@ def _stencil27_matvec_chain(p: jax.Array, axes: Tuple[str, ...],
         return 27.0 * p - box - edges
 
 
-def _ddot(a: jax.Array, b: jax.Array, axis_name: Optional[str],
+def _ddot(a: jax.Array, b: jax.Array, axes: Tuple[str, ...],
           subdomains: int = 4) -> jax.Array:
-    """paper Code 11: per-subdomain reduction(+) partials, then allreduce."""
+    """paper Code 11: per-subdomain reduction(+) partials, then allreduce
+    over the mesh `axes`."""
     with jax.named_scope(REDUCE):
         prod = (a * b).reshape(-1)
         chunks = jnp.array_split(prod, subdomains)
         partials = [jnp.sum(c, dtype=jnp.float64 if a.dtype == jnp.float64
                             else jnp.float32)
                     for c in chunks]
-        local = task_reduce(partials, "sum")
-        if axis_name is None:
-            return local
-        return lax.psum(local, axis_name)
+        return lax.psum(task_reduce(partials, "sum"), axes)
 
 
 @functools.lru_cache(maxsize=128)
 def _hpccg_solver(mesh, mesh_axes, iters: int, mode: str, subdomains: int):
     axes = normalize_mesh_axes(mesh_axes, "hpccg_solve", (1, 2, 3))
-    chained = len(axes) >= 2
-    # the reduction axes / 1-D exchange axis, in the historical spelling
-    # (bare name for slabs, tuple for chained meshes)
-    axis_name = axes if chained else axes[0]
-    if chained:
-        # trailing grid dims carry the mesh: (y, z) for a pair, (x, y, z)
-        # for a full 3-D mesh
-        cdims = tuple(range(3 - len(axes), 3))
-
-    def matvec(p, halos):
-        if chained:
-            return _stencil27_matvec_chain(p, axes, cdims, mode, halos=halos)
-        return _stencil27_matvec(p, axis_name, mode, halos=halos,
-                                 subdomains=subdomains)
-
-    def next_halos(p):
-        if chained:
-            return _exchange_chain(p, axes, cdims)
-        return exchange_halo(p, axis_name, width=1, dim=2, periodic=False)
+    # trailing grid dims carry the mesh: z for slabs, (y, z) for a pair,
+    # (x, y, z) for a full 3-D mesh
+    dims = tuple(range(3 - len(axes), 3))
 
     def local(b_loc):
         x = jnp.zeros_like(b_loc)
         r = b_loc
         p = r
-        rtrans = _ddot(r, r, axis_name, subdomains)
-        pipelined = mode == "hdot" and b_loc.shape[2] >= 4 and iters > 0
+        rtrans = _ddot(r, r, axes, subdomains)
+        pipelined = mode == "hdot" and iters > 0
 
         def step(x, r, p, rtrans, halos):
-            Ap = matvec(p, halos)
-            pAp = _ddot(p, Ap, axis_name, subdomains)
+            Ap = _stencil27_matvec_chain(p, axes, dims, mode, halos=halos)
+            pAp = _ddot(p, Ap, axes, subdomains)
             with jax.named_scope(UPDATE):
                 alpha = rtrans / pAp
                 x = x + alpha * p          # waxpby tasks
                 r = r - alpha * Ap
-            rtrans_new = _ddot(r, r, axis_name, subdomains)
+            rtrans_new = _ddot(r, r, axes, subdomains)
             with jax.named_scope(UPDATE):
                 beta = rtrans_new / rtrans
                 p = r + beta * p
@@ -675,13 +564,13 @@ def _hpccg_solver(mesh, mesh_axes, iters: int, mode: str, subdomains: int):
             def body(carry, _):
                 x, r, p, rtrans, halos = carry
                 x, r, p, rtrans = step(x, r, p, rtrans, halos)
-                halos = next_halos(p)  # for the NEXT matvec
+                halos = _exchange_chain(p, axes, dims)  # the NEXT matvec's
                 return (x, r, p, rtrans, halos), jnp.sqrt(rtrans)
 
             # drain peeled: the last iteration consumes its halos but feeds
-            # no further matvec — same dead-exchange saving as halo_scan
+            # no further matvec — same dead-exchange saving as halo_scan_nd
             (x, r, p, rtrans, halos), hist = lax.scan(
-                (body), (x, r, p, rtrans, next_halos(p)), None,
+                body, (x, r, p, rtrans, _exchange_chain(p, axes, dims)), None,
                 length=iters - 1)
             x, r, p, rtrans = step(x, r, p, rtrans, halos)
             hist = jnp.concatenate([hist, jnp.sqrt(rtrans)[None]])
@@ -695,10 +584,7 @@ def _hpccg_solver(mesh, mesh_axes, iters: int, mode: str, subdomains: int):
         (x, r, p, rtrans), hist = lax.scan(body, (x, r, p, rtrans), None, length=iters)
         return x, hist
 
-    if chained:
-        spec = P(*((None,) * (3 - len(axes)) + axes))
-    else:
-        spec = P(None, None, axis_name)
+    spec = P(*((None,) * (3 - len(axes)) + axes))
     f = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=(spec, P()))
     return jax.jit(f)
 
@@ -712,7 +598,7 @@ def hpccg_solve(b: jax.Array, mesh, mesh_axes, iters: int,
     `mesh_axes` is the unified solver topology contract: ``(z_axis,)``
     (z-stacked slabs), a ``(y_axis, z_axis)`` pair, or an
     ``(x_axis, y_axis, z_axis)`` triple — HPCCG's native full 3-D mesh.
-    Multi-axis topologies use the sequential face-message chain
+    Every topology runs one path, the sequential face-message chain
     (:func:`_exchange_chain`): each earlier dim is padded in order on the
     already-padded block, so the last dim's halo planes carry every corner
     coupling of the 27-point operator with one face ppermute pair per axis.
@@ -720,10 +606,11 @@ def hpccg_solve(b: jax.Array, mesh, mesh_axes, iters: int,
     hdot mode pipelines the matvec halo: the exchange(s) for iteration k+1
     are launched the moment p_{k+1} is formed, so they ride behind the two
     ddot allreduces, the waxpby tasks, and the next matvec's interior task
-    (its interior chunks on slabs) — only the boundary-plane tasks of the
-    next matvec wait on them. The jitted solver is cached per (mesh,
-    topology, iters, mode, subdomains) so repeated solves (and benchmark
-    timings) pay compile once."""
+    — only the boundary-plane tasks of the next matvec wait on them.
+    `subdomains` cuts only the dot products' partials (:func:`_ddot`); the
+    matvec is one task over the block. The jitted solver is cached per
+    (mesh, topology, iters, mode, subdomains) so repeated solves (and
+    benchmark timings) pay compile once."""
     with jax.profiler.TraceAnnotation(SOLVE_SPAN):
         axes = normalize_mesh_axes(mesh_axes, "hpccg_solve", (1, 2, 3))
         return _hpccg_solver(mesh, axes, iters, mode, subdomains)(b)

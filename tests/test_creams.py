@@ -67,7 +67,7 @@ def test_rhs_matches_reference(u0, mode):
     inv_dx = tuple(1 / d for d in ref.spacing(u0.shape))
     spec = P(None, None, None, "data")
     got = jax.jit(jax.shard_map(
-        lambda u: _euler_rhs(u, "data", mode, inv_dx),
+        lambda u: _euler_rhs(u, ("data",), mode, inv_dx),
         mesh=mesh, in_specs=spec, out_specs=spec))(u0)
     want = ref.rhs(u0, ref.spacing(u0.shape))
     assert got.shape == want.shape == (5,) + SHAPE

@@ -23,7 +23,7 @@ def test_readme_exists_and_covers_the_basics():
 def test_overlap_doc_exists_and_names_the_knobs():
     text = (REPO / "docs" / "overlap.md").read_text()
     for knob in ("two_phase", "hdot", "subdomains", "grad_buckets",
-                 "halo_scan_2d", "make_grid_mesh"):
+                 "halo_scan_nd", "make_grid_mesh"):
         assert knob in text, f"docs/overlap.md lost {knob}"
 
 

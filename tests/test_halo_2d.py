@@ -15,8 +15,10 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.domain import interior_boxes
-from repro.core.halo import (halo_scan_2d, halo_scan_nd, pad_with_halo_2d,
-                             stencil_apply_2d, stencil_with_halo_2d)
+from repro.core.halo import (halo_scan_nd, pad_with_halo_nd, stencil_apply_nd,
+                             stencil_with_halo_nd)
+
+GRID = (("rows", 0), ("cols", 1))
 
 
 @pytest.fixture(scope="module")
@@ -67,18 +69,17 @@ def test_stencil_hdot_2d_matches_two_phase(grid_mesh, subdomains, periodic):
     """The 2-D chunk-grid knob must not change numerics for any grainsize."""
     u = jax.random.normal(jax.random.PRNGKey(0), (24, 20), jnp.float32)
     fn = _star_fn(1)
-    want = _shmap(lambda x: stencil_apply_2d(
-        x, fn, ("rows", "cols"), 1, (0, 1), periodic, "two_phase"), grid_mesh)(u)
-    got = _shmap(lambda x: stencil_apply_2d(
-        x, fn, ("rows", "cols"), 1, (0, 1), periodic, "hdot", subdomains),
-        grid_mesh)(u)
+    want = _shmap(lambda x: stencil_apply_nd(
+        x, fn, GRID, 1, periodic, "two_phase"), grid_mesh)(u)
+    got = _shmap(lambda x: stencil_apply_nd(
+        x, fn, GRID, 1, periodic, "hdot", subdomains), grid_mesh)(u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 
 
 def scan_matches_iterated(mesh_shape, mode, width, block, steps, peel,
                           weights):
-    """halo_scan_2d (halo_scan_nd with an explicit cut) against `steps`
+    """halo_scan_nd (uniform or with an explicit cut) against `steps`
     iterated two-phase applies on a (rows, cols) mesh of `mesh_shape`, each
     chip holding `block`: are the grids, and the per-step max |new - old|,
     equal bit for bit?"""
@@ -97,18 +98,17 @@ def scan_matches_iterated(mesh_shape, mode, width, block, steps, peel,
 
     def scan(x):
         if weights is None:
-            return halo_scan_2d(x, fn, axes, width, (0, 1), steps,
-                                periodic=True, mode=mode, subdomains=(3, 2),
+            return halo_scan_nd(x, fn, GRID, width, steps, periodic=True,
+                                mode=mode, subdomains=(3, 2),
                                 partial_fn=change, peel=peel)
-        return halo_scan_nd(x, fn, tuple(zip(axes, (0, 1))), width, steps,
-                            periodic=True, mode=mode, partial_fn=change,
-                            peel=peel, weights=weights)
+        return halo_scan_nd(x, fn, GRID, width, steps, periodic=True,
+                            mode=mode, partial_fn=change, peel=peel,
+                            weights=weights)
 
     def iterate(x):
         hist = []
         for _ in range(steps):
-            new = stencil_apply_2d(x, fn, axes, width, (0, 1), True,
-                                   "two_phase")
+            new = stencil_apply_nd(x, fn, GRID, width, True, "two_phase")
             hist.append(jax.lax.pmax(change(new, x), axes))
             x = new
         return x, jnp.stack(hist)
@@ -146,7 +146,7 @@ SCAN_CASES += [
                          SCAN_CASES)
 def test_halo_scan_2d_equals_iterated_apply(mesh_shape, mode, width, block,
                                             steps, peel, weights, request):
-    """halo_scan_2d(steps=k) == k iterated two-phase 2-D applies, bit for
+    """halo_scan_nd(steps=k) == k iterated two-phase 2-D applies, bit for
     bit, grid and per-step residual: odd AND even interior sizes and step
     counts, both schedules, peeled or not, uniform or uneven interior cut;
     the 2x2 cases run in one child process on forced host devices."""
@@ -163,15 +163,16 @@ def test_stencil_with_halo_2d_uses_given_halos(grid_mesh):
     cells — including the strip corners, via the corner-free assembly."""
     k = jax.random.PRNGKey(2)
     u = jax.random.normal(k, (18, 14), jnp.float32)
-    halos = (jax.random.normal(jax.random.fold_in(k, 1), (1, 14), jnp.float32),
-             jax.random.normal(jax.random.fold_in(k, 2), (1, 14), jnp.float32),
-             jax.random.normal(jax.random.fold_in(k, 3), (18, 1), jnp.float32),
-             jax.random.normal(jax.random.fold_in(k, 4), (18, 1), jnp.float32))
+    halos = (
+        (jax.random.normal(jax.random.fold_in(k, 1), (1, 14), jnp.float32),
+         jax.random.normal(jax.random.fold_in(k, 2), (1, 14), jnp.float32)),
+        (jax.random.normal(jax.random.fold_in(k, 3), (18, 1), jnp.float32),
+         jax.random.normal(jax.random.fold_in(k, 4), (18, 1), jnp.float32)))
     fn = _star_fn(1)
-    got = jax.jit(functools.partial(stencil_with_halo_2d, stencil_fn=fn,
+    got = jax.jit(functools.partial(stencil_with_halo_nd, stencil_fn=fn,
                                     width=1, dims=(0, 1),
                                     subdomains=(2, 3)))(u, halos)
-    want = fn(pad_with_halo_2d(u, halos, 1, (0, 1)))
+    want = fn(pad_with_halo_nd(u, halos, 1, (0, 1)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 
@@ -184,7 +185,7 @@ def test_heat2d_2d_mesh_matches_slab_and_numpy(grid_mesh):
 
     u0 = heat2d_init(32, 32)
     mesh1 = make_mesh((1,), ("data",))
-    want, res_want = heat2d_solve(u0, mesh1, "data", 12, mode="two_phase")
+    want, res_want = heat2d_solve(u0, mesh1, ("data",), 12, mode="two_phase")
     for mode in ("two_phase", "hdot"):
         got, res = heat2d_solve(u0, grid_mesh, ("rows", "cols"), 12, mode=mode)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -205,7 +206,7 @@ def test_hpccg_2d_mesh_matches_slab(grid_mesh):
 
     b = jax.random.normal(jax.random.PRNGKey(3), (10, 12, 12), jnp.float32)
     mesh1 = make_mesh((1,), ("data",))
-    _, h_want = hpccg_solve(b, mesh1, "data", 15, mode="two_phase")
+    _, h_want = hpccg_solve(b, mesh1, ("data",), 15, mode="two_phase")
     for mode in ("two_phase", "hdot"):
         x, h = hpccg_solve(b, grid_mesh, ("rows", "cols"), 15, mode=mode)
         np.testing.assert_allclose(np.asarray(h), np.asarray(h_want),

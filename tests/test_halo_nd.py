@@ -214,7 +214,7 @@ def test_rk3_2d_mesh_matches_slab(grid_mesh3):
     from tests.euler_reference import random_state
 
     u0 = random_state(jax.random.PRNGKey(3), (12, 20, 32))
-    want, dt_want = rk3_solve(u0, make_mesh((1,), ("data",)), "data", 4,
+    want, dt_want = rk3_solve(u0, make_mesh((1,), ("data",)), ("data",), 4,
                               mode="two_phase")
     for mode in ("two_phase", "hdot"):
         got, dt = rk3_solve(u0, make_grid_mesh(1, 1), ("rows", "cols"), 4,
@@ -232,7 +232,7 @@ def test_hpccg_3d_mesh_matches_slab(grid_mesh3):
     from repro.launch.mesh import make_mesh
 
     b = jax.random.normal(jax.random.PRNGKey(4), (10, 12, 12), jnp.float32)
-    _, h_want = hpccg_solve(b, make_mesh((1,), ("data",)), "data", 15,
+    _, h_want = hpccg_solve(b, make_mesh((1,), ("data",)), ("data",), 15,
                             mode="two_phase")
     for mode in ("two_phase", "hdot"):
         _, h = hpccg_solve(b, grid_mesh3, AXES3, 15, mode=mode)

@@ -1,5 +1,6 @@
 """halo.py schedule machinery: interior chunk tasks, pre-exchanged-halo apply,
-and the double-buffered multi-step `halo_scan` driver. All single-device (the
+and the double-buffered multi-step `halo_scan_nd` driver, on one mesh axis
+(the slab spelling ``axes=(("data", 0),)``). All single-device (the
 multi-device equivalences live in test_system.py); numerics must be identical
 between every schedule/knob setting — the paper's safety property."""
 from __future__ import annotations
@@ -12,8 +13,10 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.core.halo import (exchange_halo, halo_scan, stencil_apply,
-                             stencil_with_halo)
+from repro.core.halo import (exchange_halo, halo_scan_nd, stencil_apply_nd,
+                             stencil_with_halo_nd)
+
+SLAB = (("data", 0),)
 
 
 @pytest.fixture(scope="module")
@@ -44,10 +47,10 @@ def _shmap(fn, mesh):
 def test_stencil_hdot_subdomains_match_two_phase(data_mesh, subdomains, periodic):
     """The interior chunk knob must not change numerics for any grainsize."""
     u = jax.random.normal(jax.random.PRNGKey(0), (24, 5), jnp.float32)
-    want = _shmap(lambda x: stencil_apply(
-        x, _avg3, "data", 1, 0, periodic, "two_phase"), data_mesh)(u)
-    got = _shmap(lambda x: stencil_apply(
-        x, _avg3, "data", 1, 0, periodic, "hdot", subdomains), data_mesh)(u)
+    want = _shmap(lambda x: stencil_apply_nd(
+        x, _avg3, SLAB, 1, periodic, "two_phase"), data_mesh)(u)
+    got = _shmap(lambda x: stencil_apply_nd(
+        x, _avg3, SLAB, 1, periodic, "hdot", subdomains), data_mesh)(u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-7)
 
@@ -55,19 +58,20 @@ def test_stencil_hdot_subdomains_match_two_phase(data_mesh, subdomains, periodic
 @pytest.mark.parametrize("mode", ["hdot", "two_phase"])
 @pytest.mark.parametrize("width,fn", [(1, _avg3), (2, _d2w2)])
 def test_halo_scan_equals_iterated_apply(data_mesh, mode, width, fn):
-    """halo_scan(steps=k) == k iterated stencil_apply calls, both schedules."""
+    """halo_scan_nd(steps=k) == k iterated stencil_apply_nd calls, both
+    schedules."""
     steps = 5
     u = jax.random.normal(jax.random.PRNGKey(1), (32, 4), jnp.float32)
 
     got, _ = jax.jit(jax.shard_map(
-        lambda x: halo_scan(x, fn, "data", width, 0, steps, periodic=True,
-                            mode=mode),
+        lambda x: halo_scan_nd(x, fn, SLAB, width, steps, periodic=True,
+                               mode=mode, subdomains=4),
         mesh=data_mesh, in_specs=(P("data"),),
         out_specs=(P("data"), P())))(u)
 
     def iterate(x):
         for _ in range(steps):
-            x = stencil_apply(x, fn, "data", width, 0, True, mode)
+            x = stencil_apply_nd(x, fn, SLAB, width, True, mode, 4)
         return x
 
     want = _shmap(iterate, data_mesh)(u)
@@ -79,9 +83,10 @@ def test_halo_scan_step_outputs(data_mesh):
     """Task partials are reduced per step and stacked per step, in order."""
     u = jnp.ones((16, 3), jnp.float32)
     _, outs = jax.jit(jax.shard_map(
-        lambda x: halo_scan(x, _avg3, "data", 1, 0, 4, periodic=True,
-                            partial_fn=lambda new, old: jnp.max(
-                                jnp.abs(new - old))),
+        lambda x: halo_scan_nd(x, _avg3, SLAB, 1, 4, periodic=True,
+                               subdomains=4,
+                               partial_fn=lambda new, old: jnp.max(
+                                   jnp.abs(new - old))),
         mesh=data_mesh, in_specs=(P("data"),),
         out_specs=(P("data"), P())))(u)
     assert outs.shape == (4,)
@@ -93,13 +98,14 @@ def test_halo_scan_degenerate_block_falls_back(data_mesh):
     numerics via the two-phase fallback."""
     u = jax.random.normal(jax.random.PRNGKey(2), (6, 3), jnp.float32)  # < 4*2
     got, _ = jax.jit(jax.shard_map(
-        lambda x: halo_scan(x, _d2w2, "data", 2, 0, 3, periodic=True),
+        lambda x: halo_scan_nd(x, _d2w2, SLAB, 2, 3, periodic=True,
+                               subdomains=4),
         mesh=data_mesh, in_specs=(P("data"),),
         out_specs=(P("data"), P())))(u)
 
     def iterate(x):
         for _ in range(3):
-            x = stencil_apply(x, _d2w2, "data", 2, 0, True, "two_phase")
+            x = stencil_apply_nd(x, _d2w2, SLAB, 2, True, "two_phase")
         return x
 
     want = _shmap(iterate, data_mesh)(u)
@@ -108,12 +114,13 @@ def test_halo_scan_degenerate_block_falls_back(data_mesh):
 
 
 def test_stencil_with_halo_uses_given_halos(data_mesh):
-    """stencil_with_halo(u, lo, hi) == two-phase apply on concat([lo, u, hi])."""
+    """stencil_with_halo_nd(u, [(lo, hi)]) == two-phase apply on
+    concat([lo, u, hi])."""
     u = jax.random.normal(jax.random.PRNGKey(3), (20, 4), jnp.float32)
     lo = jax.random.normal(jax.random.PRNGKey(4), (1, 4), jnp.float32)
     hi = jax.random.normal(jax.random.PRNGKey(5), (1, 4), jnp.float32)
-    got = jax.jit(functools.partial(stencil_with_halo, stencil_fn=_avg3,
-                                    width=1, dim=0, subdomains=3))(u, lo, hi)
+    got = jax.jit(lambda u, lo, hi: stencil_with_halo_nd(
+        u, [(lo, hi)], _avg3, width=1, dims=(0,), subdomains=3))(u, lo, hi)
     want = _avg3(jnp.concatenate([lo, u, hi], axis=0))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-7)
@@ -127,9 +134,9 @@ def test_halo_scan_peel_numerics_identical(data_mesh):
 
     def run(peel):
         return jax.jit(jax.shard_map(
-            lambda x: halo_scan(x, _avg3, "data", 1, 0, 5, periodic=True,
-                                peel=peel,
-                                partial_fn=lambda new, old: jnp.max(new)),
+            lambda x: halo_scan_nd(x, _avg3, SLAB, 1, 5, periodic=True,
+                                   subdomains=4, peel=peel,
+                                   partial_fn=lambda new, old: jnp.max(new)),
             mesh=data_mesh, in_specs=(P("data"),),
             out_specs=(P("data"), P())))(u)
 

@@ -2,7 +2,8 @@
 window sum, against a 27-slice oracle kept here: the chained hdot matvec
 (interior window sum, face sums of the received z planes, assemble), the
 two-phase matvec and ``_sum27`` itself, on 1x1x1, 1x2x2 and 2x2x2 meshes
-(and a (rows, cols) pair), local blocks of 8×8×8 and an odd 6×10×12, with
+(and a (rows, cols) pair, and z slabs on 1 and 4 devices, which run the
+same chain with one axis), local blocks of 8×8×8 and an odd 6×10×12, with
 the edge and corner cells of every block checked on their own.
 
 The multi-device cases run in one child process on forced host devices
@@ -21,7 +22,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core import stencil
 from repro.launch.mesh import GRID_AXES, GRID_AXES_3D, make_grid_mesh
 
-MESHES = [(1, 1, 1), (1, 2, 2), (2, 2, 2), (2, 2)]  # (2, 2): (rows, cols)
+# (2, 2): (rows, cols); (1,) and (4,): z slabs
+MESHES = [(1, 1, 1), (1, 2, 2), (2, 2, 2), (2, 2), (1,), (4,)]
+AXES = {1: ("z",), 2: GRID_AXES, 3: GRID_AXES_3D}
 LOCALS = [(8, 8, 8), (6, 10, 12)]
 MODES = ["hdot", "two_phase"]
 CASES = list(itertools.product(MESHES, LOCALS, MODES))
@@ -55,9 +58,9 @@ def _global_p(mesh_shape, local) -> np.ndarray:
 
 def chain_matvec(mesh_shape, local, mode) -> np.ndarray:
     """A p through `_stencil27_matvec_chain` on a mesh of `mesh_shape`."""
-    mesh = make_grid_mesh(*mesh_shape,
+    axes = AXES[len(mesh_shape)]
+    mesh = make_grid_mesh(*mesh_shape, axes=axes,
                           devices=jax.devices()[:int(np.prod(mesh_shape))])
-    axes = GRID_AXES_3D if len(mesh_shape) == 3 else GRID_AXES
     dims = tuple(range(3 - len(axes), 3))
     spec = P(*((None,) * (3 - len(axes)) + axes))
     f = jax.jit(jax.shard_map(

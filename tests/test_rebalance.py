@@ -160,16 +160,16 @@ def test_cost_model_weights_along_marginalizes():
 
 
 # ----------------------------------------------- unified solver mesh contract
-def test_normalize_mesh_axes_contract(monkeypatch):
+def test_normalize_mesh_axes_contract():
     import repro.core.stencil as stencil
 
     norm = stencil.normalize_mesh_axes
     assert norm(("data",), "heat2d_solve", (1, 2)) == ("data",)
     assert norm(["rows", "cols"], "heat2d_solve", (1, 2)) == ("rows", "cols")
 
-    monkeypatch.setattr(stencil, "_STR_AXES_WARNED", set())
-    with pytest.warns(DeprecationWarning, match="heat2d_solve"):
-        assert norm("data", "heat2d_solve", (1, 2)) == ("data",)
+    # a bare string is out of contract: no longer coerced to one axis
+    with pytest.raises(ValueError, match="heat2d_solve.*tuple"):
+        norm("data", "heat2d_solve", (1, 2))
 
     with pytest.raises(ValueError, match="hpccg_solve.*1 or 2 or 3"):
         norm(("a", "b", "c", "d"), "hpccg_solve", (1, 2, 3))
@@ -181,21 +181,6 @@ def test_normalize_mesh_axes_contract(monkeypatch):
         norm(("data", 1), "heat2d_solve", (1, 2))
     with pytest.raises(ValueError):
         norm(42, "heat2d_solve", (1, 2))
-
-
-def test_deprecated_halo_aliases_warn(monkeypatch):
-    import jax.numpy as jnp
-
-    import repro.core.halo as halo
-
-    monkeypatch.setattr(halo, "_DEPRECATION_WARNED", set())
-    u = jnp.arange(24, dtype=jnp.float32).reshape(6, 4)
-    lo, hi = jnp.zeros((1, 4)), jnp.zeros((1, 4))
-    with pytest.warns(DeprecationWarning, match="stencil_with_halo_nd"):
-        old = halo.stencil_with_halo(u, lo, hi, lambda p: p[1:-1], 1, 0, 2)
-    new = halo.stencil_with_halo_nd(u, [(lo, hi)], lambda p: p[1:-1], 1,
-                                    (0,), (2,))
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
 
 
 # --------------------------------------------- solver re-cut (single device)

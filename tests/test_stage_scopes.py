@@ -1,8 +1,8 @@
 """The HDOT stage scopes (``hdot.*`` named scopes in core/halo.py,
 core/stencil.py and core/reduction.py): every stage a solver runs is named
 in its compiled program's ``op_name`` metadata, in both schedules, on one
-device and on a 4-device mesh; the solver entry points record a host span;
-and the 1-D matvec honours its ``subdomains``.
+device and on a 4-device mesh (HPCCG's z slabs too, which run its chain
+with one axis); and the solver entry points record a host span.
 
 The 4-device cases compile in one child process on forced host devices
 (``python tests/test_stage_scopes.py`` prints their stage sets)."""
@@ -29,7 +29,8 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 # (app, mesh shape, mode) -> the stages its compiled solve names. On one
 # device Heat2D's hdot exchange carries zero halos over size-1 axes and the
-# compiler folds it away; HPCCG's chain still pads p there.
+# compiler folds it away; HPCCG's chain still pads p there, unless it has
+# one axis (a z slab), where it pads nothing and only exchanges z planes.
 CASES = {
     ("heat2d", (1, 1), "hdot"): HDOT - {halo.EXCHANGE},
     ("heat2d", (1, 1), "two_phase"): TWO_PHASE,
@@ -39,6 +40,8 @@ CASES = {
     ("hpccg", (1, 1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},
     ("hpccg", (1, 2, 2), "hdot"): HDOT | {halo.UPDATE},
     ("hpccg", (1, 2, 2), "two_phase"): TWO_PHASE | {halo.UPDATE},
+    ("hpccg", (1,), "hdot"): HDOT - {halo.EXCHANGE} | {halo.UPDATE},
+    ("hpccg", (4,), "hdot"): HDOT | {halo.UPDATE},
     # CREAMS's flux tasks, CFL max and stage updates take the same six names
     ("creams", (1, 1), "hdot"): HDOT | {halo.UPDATE},
     ("creams", (1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},
@@ -63,19 +66,23 @@ def innermost_stages(hlo_text: str) -> set:
 
 def compiled_stages(app: str, shape, mode: str) -> set:
     """Stages named in the compiled solve of `app` on a mesh of `shape`, at
-    a tiny size (each chip holds 32^2 cells, 8^3, or 5 fields of 8x16x16)."""
-    mesh = make_grid_mesh(*shape, devices=jax.devices()[:int(np.prod(shape))])
+    a tiny size (each chip holds 32^2 cells, 8^3, or 5 fields of 8x16x16).
+    A one-axis `shape` is HPCCG's z slabs."""
+    axes = {1: ("z",), 2: GRID_AXES, 3: GRID_AXES_3D}[len(shape)]
+    mesh = make_grid_mesh(*shape, axes=axes,
+                          devices=jax.devices()[:int(np.prod(shape))])
     if app == "heat2d":
-        fn = stencil._heat2d_solver(mesh, GRID_AXES, 4, mode, 4, None)
-        local, spec = (32, 32), P(*GRID_AXES)
+        fn = stencil._heat2d_solver(mesh, axes, 4, mode, 4, None)
+        local = (32, 32)
     elif app == "creams":
-        fn = stencil._rk3_solver(mesh, GRID_AXES, 2, (1.0,) * 3, mode)
-        local, spec = (5, 8, 16, 16), P(None, None, *GRID_AXES)
+        fn = stencil._rk3_solver(mesh, axes, 2, (1.0,) * 3, mode)
+        local = (5, 8, 16, 16)
     else:
-        fn = stencil._hpccg_solver(mesh, GRID_AXES_3D, 3, mode, 4)
-        local, spec = (8, 8, 8), P(*GRID_AXES_3D)
+        fn = stencil._hpccg_solver(mesh, axes, 3, mode, 4)
+        local = (8, 8, 8)
     # the mesh splits the trailing dims (a leading field axis stays whole)
     split = (1,) * (len(local) - len(shape)) + tuple(shape)
+    spec = P(*((None,) * (len(local) - len(shape)) + axes))
     arg = jax.ShapeDtypeStruct(tuple(n * m for n, m in zip(local, split)),
                                jnp.float32, sharding=NamedSharding(mesh, spec))
     return innermost_stages(fn.lower(arg).compile().as_text())
@@ -121,33 +128,6 @@ def test_solver_entry_records_host_span(app, tmp_path):
     finally:
         jax.profiler.stop_trace()
     assert _host_spans(str(tmp_path)).count(stencil.SOLVE_SPAN) == 1
-
-
-@pytest.mark.parametrize("subdomains", [2, 3])
-def test_matvec_1d_honours_subdomains(subdomains, monkeypatch):
-    """The slab matvec cuts its interior into `subdomains` chunks and keeps
-    the two-phase numerics."""
-    seen = []
-    chunks = halo._interior_chunks_nd
-
-    def spy(u, stencil_fn, width, dims, subs, weights=None):
-        seen.append(subs)
-        return chunks(u, stencil_fn, width, dims, subs, weights)
-
-    monkeypatch.setattr(halo, "_interior_chunks_nd", spy)
-    mesh = make_mesh((1,), ("z",))
-    p = jax.random.normal(jax.random.PRNGKey(0), (6, 6, 16), jnp.float32)
-
-    def matvec(mode):
-        f = jax.shard_map(
-            lambda q: stencil._stencil27_matvec(q, "z", mode,
-                                                subdomains=subdomains),
-            mesh=mesh, in_specs=P(None, None, "z"), out_specs=P(None, None, "z"))
-        return np.asarray(jax.jit(f)(p))
-
-    np.testing.assert_allclose(matvec("hdot"), matvec("two_phase"),
-                               rtol=1e-6, atol=1e-5)
-    assert seen == [(subdomains,)]
 
 
 if __name__ == "__main__":

@@ -35,8 +35,8 @@ def test_heat2d_4dev_matches_1dev_and_schedules():
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((4,), ("data",))
     u0 = heat2d_init(64, 64)
-    u_tp, r_tp = heat2d_solve(u0, mesh, "data", 10, mode="two_phase")
-    u_hd, r_hd = heat2d_solve(u0, mesh, "data", 10, mode="hdot")
+    u_tp, r_tp = heat2d_solve(u0, mesh, ("data",), 10, mode="two_phase")
+    u_hd, r_hd = heat2d_solve(u0, mesh, ("data",), 10, mode="hdot")
     print(json.dumps({
         "identical": bool(np.allclose(np.asarray(u_tp), np.asarray(u_hd), atol=1e-6)),
         "u_sum": float(np.asarray(u_hd).sum()),
@@ -233,12 +233,12 @@ def test_matmul_rs_bidirectional_ring(devices):
 
 @pytest.mark.slow
 def test_halo_scan_4dev_equals_iterated_apply():
-    """Double-buffered halo_scan == iterated stencil_apply across a real
-    4-way ring (periodic and Dirichlet)."""
+    """Double-buffered halo_scan_nd == iterated stencil_apply_nd across a
+    real 4-way ring (periodic and Dirichlet), one mesh axis."""
     code = """
     import json, jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.halo import halo_scan, stencil_apply
+    from repro.core.halo import halo_scan_nd, stencil_apply_nd
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((4,), ("data",))
     avg3 = lambda p: (p[:-2] + p[1:-1] + p[2:]) / 3.0
@@ -246,11 +246,13 @@ def test_halo_scan_4dev_equals_iterated_apply():
     ok = {}
     for periodic in (False, True):
         got, _ = jax.jit(jax.shard_map(
-            lambda x: halo_scan(x, avg3, "data", 1, 0, 6, periodic=periodic),
+            lambda x: halo_scan_nd(x, avg3, (("data", 0),), 1, 6,
+                                   periodic=periodic, subdomains=4),
             mesh=mesh, in_specs=(P("data"),), out_specs=(P("data"), P())))(u)
         def iterate(x):
             for _ in range(6):
-                x = stencil_apply(x, avg3, "data", 1, 0, periodic, "hdot")
+                x = stencil_apply_nd(x, avg3, (("data", 0),), 1, periodic,
+                                     "hdot", 4)
             return x
         want = jax.jit(jax.shard_map(iterate, mesh=mesh, in_specs=(P("data"),),
                                      out_specs=P("data")))(u)
@@ -273,7 +275,7 @@ def test_heat2d_2d_meshes_match_1dev_oracle():
     from repro.core.stencil import heat2d_init, heat2d_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
     u0 = heat2d_init(64, 64)
-    ref, rres = heat2d_solve(u0, make_mesh((1,), ("data",)), "data", 10,
+    ref, rres = heat2d_solve(u0, make_mesh((1,), ("data",)), ("data",), 10,
                              mode="two_phase")
     ok = {}
     for rc in ((2, 2), (4, 1), (1, 4)):
@@ -284,7 +286,7 @@ def test_heat2d_2d_meshes_match_1dev_oracle():
                 np.allclose(np.asarray(u), np.asarray(ref), rtol=1e-5, atol=1e-6)
                 and np.allclose(np.asarray(res), np.asarray(rres), rtol=1e-4))
     u0b = heat2d_init(66, 70)   # odd 33x35 shards on 2x2
-    refb, _ = heat2d_solve(u0b, make_mesh((1,), ("data",)), "data", 7,
+    refb, _ = heat2d_solve(u0b, make_mesh((1,), ("data",)), ("data",), 7,
                            mode="two_phase")
     ub, _ = heat2d_solve(u0b, make_grid_mesh(2, 2), ("rows", "cols"), 7,
                          mode="hdot")
@@ -305,7 +307,7 @@ def test_hpccg_2d_mesh_matches_1dev_oracle():
     from repro.core.stencil import hpccg_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
     b = jax.random.normal(jax.random.PRNGKey(2), (12, 16, 16), jnp.float32)
-    _, href = hpccg_solve(b, make_mesh((1,), ("data",)), "data", 20,
+    _, href = hpccg_solve(b, make_mesh((1,), ("data",)), ("data",), 20,
                           mode="two_phase")
     ok = {}
     for rc in ((2, 2), (4, 1), (1, 4)):
@@ -353,7 +355,7 @@ def test_rk3_2d_mesh_matches_1dev_oracle():
     from repro.launch.mesh import make_grid_mesh, make_mesh
     from tests.euler_reference import random_state
     u0 = random_state(jax.random.PRNGKey(0), (8, 48, 48))
-    ref, dt_ref = rk3_solve(u0, make_mesh((1,), ("data",)), "data", 5,
+    ref, dt_ref = rk3_solve(u0, make_mesh((1,), ("data",)), ("data",), 5,
                             mode="two_phase")
     ok = {}
     for rc in ((2, 2), (4, 1), (1, 4)):
@@ -383,7 +385,7 @@ def test_hpccg_3d_mesh_matches_1dev_oracle():
     from repro.core.stencil import hpccg_solve
     from repro.launch.mesh import make_grid_mesh, make_mesh
     b = jax.random.normal(jax.random.PRNGKey(2), (12, 20, 20), jnp.float32)
-    _, href = hpccg_solve(b, make_mesh((1,), ("data",)), "data", 20,
+    _, href = hpccg_solve(b, make_mesh((1,), ("data",)), ("data",), 20,
                           mode="two_phase")
     ok = {}
     for parts in ((2, 2, 2), (4, 2, 1), (1, 2, 4)):
