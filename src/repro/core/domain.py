@@ -317,8 +317,35 @@ class Domain:
         return halo_cells(self.box, self.global_shape, width, dims, periodic)
 
 
+def tile_span(extent: int, width: int, quantum: int = 1) -> Tuple[int, int]:
+    """The interior [lo, hi) of one dim cut on multiples of `quantum`: lo the
+    first multiple at or above `width`, hi the last at or below
+    `extent - width`. The boundary faces own [0, lo) and [hi, extent), so
+    each is at least `width` thick; ``quantum=1`` gives [width,
+    extent - width)."""
+    return -(-width // quantum) * quantum, (extent - width) // quantum * quantum
+
+
+def face_boxes(shape: Sequence[int], spans: Sequence[Tuple[int, int]]
+               ) -> List[Box]:
+    """The "onion" boundary faces of a block whose interior is the box of
+    `spans` (one [lo, hi) per dim): face (k, lo) owns [0, lo_k) along dim k,
+    the interior span along every earlier dim and the full extent along
+    every later one; face (k, hi) owns [hi_k, extent_k) likewise. Returned
+    k-major, lo before hi. With :func:`interior_boxes` over the same spans
+    they tile the block exactly once."""
+    out = []
+    for k, (lo, hi) in enumerate(spans):
+        for a, b in ((0, lo), (hi, shape[k])):
+            start = [s[0] for s in spans[:k]] + [a] + [0] * (len(shape) - k - 1)
+            stop = [s[1] for s in spans[:k]] + [b] + list(shape[k + 1:])
+            out.append(Box(tuple(start), tuple(stop)))
+    return out
+
+
 def interior_boxes(shape: Sequence[int], width: int,
-                   grid: Sequence[int], weights=None) -> List[Box]:
+                   grid: Sequence[int], weights=None,
+                   quantum: Optional[Sequence[int]] = None) -> List[Box]:
     """Task-level reuse of :func:`decompose_grid` on the INTERIOR of a local
     block: the cells [width, extent-width) per dim are split into a `grid` of
     chunk boxes (local-block coordinates). This is the 2-D over-decomposition
@@ -328,10 +355,25 @@ def interior_boxes(shape: Sequence[int], width: int,
 
     `weights` (optional, one entry per dim, sized against the INTERIOR
     extent) produces the measured-cost uneven cut of :func:`split_ranges`;
-    ``weights=None`` is bit-identical to the historical uniform grid."""
+    ``weights=None`` is bit-identical to the historical uniform grid.
+
+    `quantum` (optional, one multiple per dim) snaps every cut to the
+    nearest multiple within the dim's :func:`tile_span`, so chunks start
+    and end on tiles and the cells between `width` and the first tile
+    boundary go to the faces; the chunk count is kept (a snapped chunk may
+    be empty). A quantum of 1 leaves the cut as it is."""
     inner = [max(0, e - 2 * width) for e in shape]
     shift = (width,) * len(tuple(shape))
-    return [b.shifted(shift) for b in decompose_grid(inner, grid, weights)]
+    boxes = [b.shifted(shift) for b in decompose_grid(inner, grid, weights)]
+    if quantum is None:
+        return boxes
+    spans = [tile_span(e, width, q) for e, q in zip(shape, quantum)]
+
+    def snap(idx):
+        return tuple(min(max((x + q // 2) // q * q, lo), hi)
+                     for x, q, (lo, hi) in zip(idx, quantum, spans))
+
+    return [Box(snap(b.start), snap(b.stop)) for b in boxes]
 
 
 def interior_cuts(shape: Sequence[int], width: int, grid: Sequence[int],

@@ -43,7 +43,8 @@ The N-D family additionally takes ``weights=`` — per-dim explicit chunk
 extents (the canonical cuts from :func:`repro.core.domain.interior_cuts`) —
 so a measured-cost re-partition produces UNEVEN interior chunk grids while
 the onion face partition (and thus the ppermute schedule) is untouched: the
-faces depend only on `width`, never on where the interior is cut.
+faces depend only on `width` and the block's tiles, never on where the
+interior is cut.
 
 All functions run inside ``shard_map`` bodies; `axis_name` names the mesh axis
 that carries the process-level domain decomposition for `dim`.
@@ -57,7 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.domain import interior_boxes
+from repro.core.domain import face_boxes, interior_boxes, tile_span
 
 # One decomposed dim: (mesh_axis_name, array_dim).
 Axes = Sequence[Tuple[str, int]]
@@ -145,6 +146,9 @@ def pad_with_halo(u: jax.Array, axis_name: str, width: int, dim: int,
 #   interior: [w, n_j - w) on every decomposed dim, cut into a grid of chunk
 #   tasks by `interior_boxes` — the process-level partition scheme reused at
 #   task level, per the paper.
+# halo_scan_nd widens a face's band from w to a tile (`_tile_quanta`) and
+# snaps the chunk cuts to tiles, so every task writes whole tiles: the
+# interior is then [lo_j, hi_j) of `tile_span`, and w stays the halo.
 # Face (k, ·) consumes ONLY axis k's halo plus restricted slices of the
 # later axes' halos (zero in the corner ghosts, which star stencils never
 # read), so each halo ppermute pair has exactly two consumer tasks.
@@ -194,22 +198,32 @@ def pad_with_halo_nd(u: jax.Array, halos, width: int,
 
 
 def _face_src_nd(u: jax.Array, halos, k: int, side: str, width: int,
-                 dims: Sequence[int]) -> jax.Array:
+                 dims: Sequence[int], spans=None) -> jax.Array:
     """Ghost-extended source for face (k, side) — the ONLY consumer of axis
     k's `side` halo. Along earlier dims the face outputs the interior range,
-    so u's own cells are the ghosts (full extent, no halo needed); along
-    later dims the face spans the full extent, so their halos are stitched
-    in, restricted to this face's cells and zero-padded into the corners."""
+    so u's own cells are the ghosts (no halo needed); along later dims the
+    face spans the full extent, so their halos are stitched in, restricted
+    to this face's cells and zero-padded into the corners. `spans` gives
+    each decomposed dim's interior [lo, hi) (default [w, n - w)): the face
+    owns the band outside it, which may be thicker than the halo."""
     w = width
     dk = dims[k]
     nk = u.shape[dk]
+    spans = spans or [(w, u.shape[d] - w) for d in dims]
+    halos = list(halos)
+    for j in range(k):  # restrict u and the halos to the earlier interiors
+        a, b = spans[j][0] - w, spans[j][1] + w
+        if (a, b) != (0, u.shape[dims[j]]):
+            u = _sl(u, dims[j], a, b)
+            halos = [h if i < k else tuple(_sl(x, dims[j], a, b) for x in h)
+                     for i, h in enumerate(halos)]
     lo_k, hi_k = halos[k]
     if side == "lo":
-        cells = (0, 2 * w)          # the u-cells adjacent to this face
+        cells = (0, spans[k][0] + w)  # the u-cells this face's stencil reads
         src = jnp.concatenate([lo_k, _sl(u, dk, *cells)], axis=dk)
         zk = (w, 0)                 # where axis k's halo sits inside src
     else:
-        cells = (nk - 2 * w, nk)
+        cells = (spans[k][1] - w, nk)
         src = jnp.concatenate([_sl(u, dk, *cells), hi_k], axis=dk)
         zk = (0, w)
     for j in range(k + 1, len(dims)):
@@ -271,26 +285,43 @@ def _chunk_grid_nd(ext: Sequence[int], width: int,
     return ks, wts
 
 
-def _chunk_tasks_nd(u: jax.Array,
-                    stencil_fn: Callable[[jax.Array], jax.Array],
-                    width: int, dims: Sequence[int],
-                    subdomains: Tuple[int, ...], weights=None):
-    """The interior chunk tasks: cells [w, n-w) per decomposed dim as an N-D
-    grid of independent chunks, cut by `interior_boxes` — the process-level
-    partition scheme reused at task level. A chunk reads only its subdomain
-    plus `width` ghosts. Returns the chunk outputs and their boxes, row-major
-    over the chunk grid, and the grid's per-dim chunk counts."""
-    w = width
+def _box_src(u: jax.Array, box, width: int, dims: Sequence[int]) -> jax.Array:
+    """An interior box's cells plus `width` ghosts along each decomposed
+    dim: what a chunk task's stencil reads."""
+    for lvl, d in enumerate(dims):
+        u = _sl(u, d, box.start[lvl] - width, box.stop[lvl] + width)
+    return u
+
+
+def _task_cut(u, dims: Sequence[int], width: int,
+              subdomains: Tuple[int, ...], weights=None):
+    """:func:`halo_scan_nd`'s task partition of a block (`u` an array or
+    its shape and dtype): each decomposed dim's interior span, the onion
+    face boxes and the interior chunk boxes, all along the decomposed dims,
+    cut on the tiles of :func:`_tile_quanta`."""
     ext = [u.shape[d] for d in dims]
-    ks, wts = _chunk_grid_nd(ext, w, subdomains, weights)
-    boxes = interior_boxes(ext, w, ks, wts)  # row-major over the ks grid
-    outs = []
-    for b in boxes:
-        src = u
-        for lvl, d in enumerate(dims):
-            src = _sl(src, d, b.start[lvl] - w, b.stop[lvl] + w)
-        outs.append(stencil_fn(src))
-    return outs, boxes, ks
+    ks, wts = _chunk_grid_nd(ext, width, subdomains, weights)
+    quanta = _tile_quanta(u, dims, width, ks)
+    spans = [tile_span(n, width, q) for n, q in zip(ext, quanta)]
+    return (spans, face_boxes(ext, spans),
+            interior_boxes(ext, width, ks, wts, quanta))
+
+
+def _tile_quanta(u: jax.Array, dims: Sequence[int], width: int,
+                 ks: Sequence[int]) -> List[int]:
+    """Per decomposed dim, the multiple the task boxes are cut on: the
+    block's tile along that dim — 128 lanes on the last array dim, 32 bytes
+    of sublanes (8 float32, 16 bfloat16 rows) on the second-to-last — so
+    every task writes whole tiles and its stencil can fuse into its write.
+    Other dims, and a dim whose tile-aligned interior holds fewer tiles than
+    chunks, take 1: the cut of [width, n - width) as it is."""
+    qs = []
+    for d, k in zip(dims, ks):
+        q = {u.ndim - 1: 128,
+             u.ndim - 2: max(1, 32 // u.dtype.itemsize)}.get(d, 1)
+        lo, hi = tile_span(u.shape[d], width, q)
+        qs.append(q if (hi - lo) // q >= k else 1)
+    return qs
 
 
 def _interior_chunks_nd(u: jax.Array,
@@ -298,14 +329,19 @@ def _interior_chunks_nd(u: jax.Array,
                         width: int, dims: Sequence[int],
                         subdomains: Tuple[int, ...],
                         weights=None) -> jax.Array:
-    """The interior as one array: the chunk tasks of :func:`_chunk_tasks_nd`
-    concatenated back together. Chunks are disjoint work the latency-hiding
-    scheduler interleaves with every axis's ppermutes. `weights` (per-dim
-    explicit chunk extents) makes the grid UNEVEN — the measured-cost re-cut —
-    without touching the face partition."""
+    """The interior chunk tasks, concatenated back together: cells [w, n-w)
+    per decomposed dim as an N-D grid of independent chunks, cut by
+    `interior_boxes` — the process-level partition scheme reused at task
+    level. A chunk reads only its subdomain plus `width` ghosts. Chunks are
+    disjoint work the latency-hiding scheduler interleaves with every axis's
+    ppermutes. `weights` (per-dim explicit chunk extents) makes the grid
+    UNEVEN — the measured-cost re-cut — without touching the face
+    partition."""
     with jax.named_scope(INTERIOR):
-        outs, _, ks = _chunk_tasks_nd(u, stencil_fn, width, dims, subdomains,
-                                      weights)
+        ext = [u.shape[d] for d in dims]
+        ks, wts = _chunk_grid_nd(ext, width, subdomains, weights)
+        outs = [stencil_fn(_box_src(u, b, width, dims))  # row-major
+                for b in interior_boxes(ext, width, ks, wts)]
         with jax.named_scope(ASSEMBLE):
             for lvl in range(len(ks) - 1, -1, -1):  # row-major -> nested concat
                 k = ks[lvl]
@@ -421,22 +457,35 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
     its exchange there; with an odd count the last step follows the loop
     and the compiler drops its unused exchange from the optimized program.
 
+    Tile-aligned tasks: on the block's last two array dims a face owns a
+    band a tile thick (128 lanes; 8 float32 or 16 bfloat16 rows) and the
+    chunk cuts are snapped to tiles (:func:`repro.core.domain.tile_span`,
+    ``interior_boxes(quantum=)``), so every task writes whole tiles and the
+    compiler fuses its stencil into its write, in place in the spare block.
+    A dim whose interior holds fewer tiles than chunks keeps the cut of
+    [w, n - w). The halo stays `width` cells.
+
     `partial_fn(new, old)` optionally gives a per-step output the paper's
     Code 5 way: each task maps its own new cells and the old cells they
     replace to a partial (e.g. ``max |new - old|`` for a residual), taken in
     the task's own stage scope, and the step's partials are max-combined by
     :func:`repro.core.reduction.hdot_reduce` over the tasks and the
-    decomposition's mesh axes. A partial reads only the cells its task's
-    stencil reads, so it adds no pass over the blocks;
-    an `f(new, old)` over both whole blocks after the step would, and would
-    make the compiler copy the old block, which the next step overwrites.
+    decomposition's mesh axes. A partial re-applies `stencil_fn` to its
+    task's source, read through an `optimization_barrier`: the same cells
+    in the same order, so the same values, while the task's write stays the
+    stencil's only consumer (a second consumer makes the compiler write the
+    task's cells to a temporary and copy them into the block). That is one
+    more read of the source and no write; an `f(new, old)` over both whole
+    blocks after the step would read both and make the compiler copy the
+    old block, which the next step overwrites.
     The stacked per-step results are returned as the second element (None
     without `partial_fn`). Numerics are identical to `steps` iterated calls
     of :func:`stencil_apply_nd` — asserted in tests. `unroll` is forwarded
     to lax.scan over the loop's trips (the HLO-inspection tests unroll fully
     so every exchange is a countable op definition). `weights` (per-dim
-    explicit chunk extents from :func:`repro.core.domain.interior_cuts`)
-    cuts the interior chunk grid unevenly — the face partition and the
+    explicit chunk extents of [w, n - w) from
+    :func:`repro.core.domain.interior_cuts`) cuts the interior chunk grid
+    unevenly, each cut snapped to the tile — the face partition and the
     ppermute schedule are unchanged, so a measured-cost re-cut never alters
     the communication shape.
     """
@@ -465,7 +514,20 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
             return u_new, reduce_partials([part])
         return lax.scan(body, u, None, length=steps, unroll=unroll)
 
-    subdomains = _norm_subn(subdomains, len(dims))
+    spans, fboxes, cboxes = _task_cut(u, dims, w,
+                                      _norm_subn(subdomains, len(dims)),
+                                      weights)
+    # The tasks, faces first (k-major, lo before hi), each as its box along
+    # the decomposed dims and the source its stencil reads from a block.
+    sides = [(k, side) for k in range(len(dims)) for side in ("lo", "hi")]
+    tasks = [(FACES, box, partial(_face_src_nd, k=k, side=side, width=w,
+                                  dims=dims, spans=spans))
+             for box, (k, side) in zip(fboxes, sides)]
+    tasks += [(INTERIOR, box,
+               lambda blk, halos, box=box: _box_src(blk, box, w, dims))
+              for box in cboxes
+              if box.size]  # a snapped or explicit cut may hold empty chunks
+    nfaces = len(fboxes)
 
     def exchange_from_faces(faces):
         # The new block's axis-k edges, stitched from face outputs alone —
@@ -477,10 +539,13 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
         halos_next = []
         with jax.named_scope(EXCHANGE):
             for k, (a, dk) in enumerate(axes):
-                lo_e, hi_e = faces[k]
+                lo_e, hi_e = faces[2 * k:2 * k + 2]
                 nk = ext[k]
+                if spans[k] != (w, nk - w):  # a band thicker than w
+                    lo_e = _edge(lo_e, dk, "lo", w)
+                    hi_e = _edge(hi_e, dk, "hi", w)
                 for j in reversed(range(k)):
-                    lo_j, hi_j = faces[j]
+                    lo_j, hi_j = faces[2 * j:2 * j + 2]
                     lo_e = jnp.concatenate(
                         [_sl(lo_j, dk, 0, w), lo_e, _sl(hi_j, dk, 0, w)],
                         axis=dims[j])
@@ -490,41 +555,37 @@ def halo_scan_nd(u: jax.Array, stencil_fn: Callable[[jax.Array], jax.Array],
                 halos_next.append(exchange_edges(lo_e, hi_e, a, periodic))
         return halos_next
 
-    def face_start(k, side):
-        # face (k, side)'s first cell along the decomposed dims (the onion)
-        return tuple(w if j < k else
-                     (ext[k] - w if j == k and side == "hi" else 0)
-                     for j in range(len(dims)))
-
     def step(src, dst, halos, exchange):
         """One step of `src`, each task writing its cells into `dst`;
         returns (dst, the next step's halos or None, the reduced partials
         or None)."""
-        faces = _faces_nd(src, halos, stencil_fn, w, dims)
+        with jax.named_scope(FACES):
+            faces = [stencil_fn(src_of(src, halos))
+                     for _, _, src_of in tasks[:nfaces]]
         halos_next = exchange_from_faces(faces) if exchange else None
-        tasks = [(FACES, out, face_start(k, side))
-                 for k, pair in enumerate(faces)
-                 for side, out in zip(("lo", "hi"), pair)]
         with jax.named_scope(INTERIOR):
-            outs, boxes, _ = _chunk_tasks_nd(src, stencil_fn, w, dims,
-                                             subdomains, weights)
-        tasks += [(INTERIOR, out, b.start) for out, b in zip(outs, boxes)]
+            outs = faces + [stencil_fn(src_of(src, halos))
+                            for _, _, src_of in tasks[nfaces:]]
+        # The partials read `src` through a barrier, so each re-derives its
+        # task's stencil in a reduction of its own: the task's write stays
+        # the only consumer of its stencil, and the compiler fuses the
+        # stencil into that write, in place in `dst`.
+        view = (lax.optimization_barrier(src) if partial_fn is not None
+                else None)
         parts = []
-        for scope, out, start in tasks:
-            if out.size == 0:  # an explicit cut may hold empty chunks
-                continue
+        for (scope, box, src_of), out in zip(tasks, outs):
             at = [0] * src.ndim
-            for d, s in zip(dims, start):
+            for d, s in zip(dims, box.start):
                 at[d] = s
-            with jax.named_scope(ASSEMBLE):
+            # the task's own scope: its stencil fuses into the write and
+            # the partial's reduction, whose time must read as that stage's
+            with jax.named_scope(scope):
                 dst = lax.dynamic_update_slice(dst, out, at)
-            if partial_fn is not None:
-                # the task's own scope: the compiler fuses the partial into
-                # the task's stencil, whose time must read as that stage's
-                with jax.named_scope(scope):
-                    old = lax.slice(src, at, [a + n for a, n in
-                                              zip(at, out.shape)])
-                    parts.append(partial_fn(out, old))
+                if partial_fn is not None:
+                    old = lax.slice(view, at, [a + n for a, n in
+                                               zip(at, out.shape)])
+                    parts.append(partial_fn(stencil_fn(src_of(view, halos)),
+                                            old))
         return dst, halos_next, (reduce_partials(parts)
                                  if partial_fn is not None else None)
 

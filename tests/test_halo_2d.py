@@ -140,6 +140,21 @@ SCAN_CASES += [
     pytest.param(mesh, "hdot", 1, (17, 13), 3, True, ((2, 9, 4), (8, 3)),
                  id=f"{mesh[0]}x{mesh[1]}-uneven-cut")
     for mesh in ((1, 1), (2, 2))]
+# Blocks large enough for the tile-aligned cut (8 rows, 128 lanes): faces
+# a tile thick, chunks on tiles; the uneven cut is snapped to tiles.
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (64, 512), steps, peel, None,
+                 id=f"{mesh[0]}x{mesh[1]}-aligned-steps{steps}-"
+                    f"{'peeled' if peel else 'unpeeled'}")
+    for mesh in ((1, 1), (2, 2)) for steps in (1, 2, 3)
+    for peel in (True, False)]
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (64, 512), 3, True, ((2, 9, 51), (300, 210)),
+                 id=f"{mesh[0]}x{mesh[1]}-aligned-uneven-cut")
+    for mesh in ((1, 1), (2, 2))]
+SCAN_CASES += [
+    pytest.param((1, 1), "hdot", 2, (64, 512), 3, True, None,
+                 id="1x1-aligned-width2")]
 
 
 @pytest.mark.parametrize("mesh_shape,mode,width,block,steps,peel,weights",

@@ -73,6 +73,71 @@ def test_interior_boxes_partition_3d():
     assert cells == want
 
 
+# (block, dtype, decomposed dims, width, chunks a dim, weights, the tile
+# quantum each dim must be cut on: 1 where the block keeps the cut of
+# [width, n - width)).
+CUT_CASES = [
+    pytest.param((64, 512), jnp.float32, (0, 1), 1, (3, 2), None, (8, 128),
+                 id="2d-f32"),
+    pytest.param((96, 512), jnp.bfloat16, (0, 1), 1, (3, 2), None, (16, 128),
+                 id="2d-bf16"),
+    pytest.param((64, 512), jnp.bfloat16, (0, 1), 1, (3, 2), None, (1, 128),
+                 id="2d-bf16-rows-too-few-tiles"),
+    pytest.param((64, 512), jnp.float32, (0, 1), 2, (3, 2), None, (8, 128),
+                 id="2d-f32-width2"),
+    pytest.param((16384, 16384), jnp.float32, (0, 1), 1, (4, 4), None,
+                 (8, 128), id="2d-f32-16k"),
+    pytest.param((64, 512), jnp.float32, (0, 1), 1, (3, 2),
+                 ((2, 9, 51), (300, 210)), (8, 128), id="2d-f32-uneven-cut"),
+    pytest.param((64, 512), jnp.float32, (0,), 1, (4,), None, (8,),
+                 id="slab-f32"),
+    pytest.param((6, 32, 384), jnp.float32, (0, 1, 2), 1, (2, 2, 1), None,
+                 (1, 8, 128), id="3d-f32"),
+    pytest.param((17, 13), jnp.float32, (0, 1), 1, (3, 2), None, (1, 1),
+                 id="small-fallback"),
+    pytest.param((11, 9, 13), jnp.float32, (0, 1, 2), 1, (2, 2, 1),
+                 ((6, 3), None, (0, 4, 7)), (1, 1, 1),
+                 id="small-fallback-uneven-cut"),
+]
+
+
+@pytest.mark.parametrize("block,dtype,dims,width,ks,weights,quanta",
+                         CUT_CASES)
+def test_task_cut_tiles_block(block, dtype, dims, width, ks, weights, quanta):
+    """halo_scan_nd's task partition: the faces and chunk boxes tile the
+    block exactly once; on a tiled dim every chunk starts and ends on a
+    multiple of the tile and each face is at least `width` thick; the chunk
+    count is the grid's, uneven cut or not; where the block is too small
+    the chunks are exactly `interior_boxes`' cut of [width, n - width)."""
+    from repro.core.domain import face_boxes
+    from repro.core.halo import _task_cut
+
+    u = jax.ShapeDtypeStruct(block, dtype)
+    ext = [block[d] for d in dims]
+    spans, faces, chunks = _task_cut(u, dims, width, ks, weights)
+    counts = [k if wd is None else len(wd)
+              for k, wd in zip(ks, weights or [None] * len(ks))]
+    assert len(faces) == 2 * len(dims)
+    assert len(chunks) == int(np.prod(counts))
+    for (lo, hi), n, q in zip(spans, ext, quanta):
+        assert lo % q == 0 and hi % q == 0 and lo >= width and n - hi >= width
+        assert (q == 1) == ((lo, hi) == (width, n - width))
+    for b in chunks:
+        assert all(a % q == 0 and o % q == 0
+                   for a, o, q in zip(b.start, b.stop, quanta))
+    if set(quanta) == {1}:
+        assert chunks == interior_boxes(ext, width, counts, weights)
+    assert faces == face_boxes(ext, spans)
+    # each cell of the decomposed dims in exactly one box: the box sizes sum
+    # to the block's, and no two boxes overlap
+    boxes = faces + [b for b in chunks if b.size]
+    assert sum(b.size for b in boxes) == int(np.prod(ext))
+    for i, a in enumerate(boxes):
+        for b in boxes[i + 1:]:
+            assert any(a.stop[d] <= b.start[d] or b.stop[d] <= a.start[d]
+                       for d in range(len(ext))), (a, b)
+
+
 @pytest.mark.parametrize("subdomains", [(1, 1, 1), (2, 2, 2), (3, 2, 1), 2,
                                         (8, 8, 8)])
 @pytest.mark.parametrize("periodic", [False, True])
@@ -147,6 +212,14 @@ SCAN_CASES += [
                  ((6, 3), None, (0, 4, 7)),
                  id=f"{'x'.join(map(str, mesh))}-uneven-cut")
     for mesh in ((1, 1, 1), (2, 2, 2))]
+# A block large enough for the tile-aligned cut of its last two dims (8
+# rows, 128 lanes): faces a tile thick, chunks on tiles.
+SCAN_CASES += [
+    pytest.param(mesh, "hdot", 1, (6, 32, 384), steps, peel, None,
+                 id=f"{'x'.join(map(str, mesh))}-aligned-steps{steps}-"
+                    f"{'peeled' if peel else 'unpeeled'}")
+    for mesh in ((1, 1, 1), (2, 2, 2)) for steps in (1, 2, 3)
+    for peel in (True, False)]
 
 
 @pytest.mark.parametrize("mesh_shape,mode,width,block,steps,peel,weights",

@@ -113,6 +113,34 @@ def test_interior_boxes_weighted_cover_and_none_identity():
     assert cover.sum() == interior.size  # nothing leaks into the halo frame
 
 
+@pytest.mark.parametrize("shape,width,grid,weights,quantum", [
+    ((64, 512), 1, (3, 2), None, (8, 128)),
+    ((64, 512), 1, (3, 2), ([5.0] * 10 + [1.0] * 52, None), (8, 128)),
+    ((64, 512), 1, (3, 2), ((2, 9, 51), (300, 210)), (8, 128)),
+    ((96, 512), 2, (4, 2), ((1, 1, 1, 89), None), (16, 128)),
+    ((20, 18), 1, (3, 2), ([5.0] * 6 + [1.0] * 12, None), (1, 1)),
+])
+def test_interior_boxes_snapped_to_quantum(shape, width, grid, weights,
+                                           quantum):
+    """A cut snapped to a quantum keeps its chunk count and order, puts
+    every cut on a multiple within `tile_span`, and moves no cut by more
+    than half a quantum (or to the span's ends); a quantum of 1 is the
+    unsnapped cut."""
+    from repro.core.domain import tile_span
+
+    plain = interior_boxes(shape, width, grid, weights)
+    snapped = interior_boxes(shape, width, grid, weights, quantum)
+    assert len(snapped) == len(plain)
+    spans = [tile_span(n, width, q) for n, q in zip(shape, quantum)]
+    for p, s in zip(plain, snapped):
+        for x, y, q, (lo, hi) in zip(p.start + p.stop, s.start + s.stop,
+                                     quantum * 2, spans * 2):
+            assert y % q == 0 and lo <= y <= hi
+            assert abs(y - x) <= q // 2 or y in (lo, hi)
+    if set(quantum) == {1}:
+        assert snapped == plain
+
+
 def test_interior_cuts_matches_boxes():
     shape, width, grid = (20, 18), 1, (3, 2)
     w = ([5.0] * 6 + [1.0] * 12, None)

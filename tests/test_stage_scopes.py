@@ -31,10 +31,12 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 # device Heat2D's hdot exchange carries zero halos over size-1 axes and the
 # compiler folds it away; HPCCG's chain still pads p there, unless it has
 # one axis (a z slab), where it pads nothing and only exchanges z planes.
+# Heat2D's hdot tasks write their cells in place under their own scopes, so
+# nothing of its solve is named `assemble`.
 CASES = {
-    ("heat2d", (1, 1), "hdot"): HDOT - {halo.EXCHANGE},
+    ("heat2d", (1, 1), "hdot"): HDOT - {halo.EXCHANGE, halo.ASSEMBLE},
     ("heat2d", (1, 1), "two_phase"): TWO_PHASE,
-    ("heat2d", (2, 2), "hdot"): HDOT,
+    ("heat2d", (2, 2), "hdot"): HDOT - {halo.ASSEMBLE},
     ("heat2d", (2, 2), "two_phase"): TWO_PHASE,
     ("hpccg", (1, 1, 1), "hdot"): HDOT | {halo.UPDATE},
     ("hpccg", (1, 1, 1), "two_phase"): TWO_PHASE | {halo.UPDATE},

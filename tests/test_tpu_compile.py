@@ -163,16 +163,42 @@ def _loop_ops(module, opcodes, shape):
     return loops
 
 
-# (mesh, block-sized copies a loop may hold). On 2x2 the compiler lays the
-# carried blocks out for the column halos and copies one back a trip; the
-# concatenating schedule copied two a sweep.
-@pytest.mark.parametrize("mesh_shape,copies", [((1, 1), 0), ((2, 2), 1)],
+def _loop_instructions(module):
+    """For each while loop of `module`, every instruction of its computations
+    and of everything they call, each with the name of its computation."""
+    out = {}
+    for _, w in module.all_instructions():
+        if w.opcode != "while":
+            continue
+        todo, seen, found = list(w.called_computations), set(), []
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in module.computations:
+                continue
+            seen.add(name)
+            for i in module.computations[name].instructions:
+                todo.extend([*i.called_computations,
+                             *_FUSED.findall(i.attr_text)])
+                found.append((i, name))
+        out[w.name] = found
+    assert out, "the compiled solve has no while loop"
+    return out
+
+
+# (mesh, block-sized copies a loop may hold). The concatenating schedule
+# copied two blocks a sweep; the unaligned task writes left one relayout a
+# trip on 2x2; the tile-aligned writes leave none.
+@pytest.mark.parametrize("mesh_shape,copies", [((1, 1), 0), ((2, 2), 0)],
                          ids=["1x1", "2x2"])
 def test_heat2d_hdot_loop_writes_blocks_in_place(topo, one_chip, mesh_shape,
                                                  copies):
     """The hdot sweep loop writes each task's cells into the carried spare
-    block: no block-sized concatenate inside it, and no block-sized copy on
-    1x1 (at most one on 2x2)."""
+    block: no block-sized concatenate inside it, and no block-sized copy.
+    The task boxes lie on (8, 128) tiles, so each task's stencil fuses into
+    its write: every block-shaped `dynamic-update-slice` is an output of a
+    fusion that also holds the stencil's adds, one for each of the 20 tasks
+    (16 chunks, 4 faces) of each of the trip's 2 sweeps; none stands alone,
+    and no fusion writes a chunk to a temporary of its own."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -191,3 +217,22 @@ def test_heat2d_hdot_loop_writes_blocks_in_place(topo, one_chip, mesh_shape,
     for ops in loops.values():
         assert "concatenate" not in {op for op, _ in ops}, loops
         assert len(ops) <= copies, loops
+    for found in _loop_instructions(module).values():
+        fusions = {_FUSED.findall(i.attr_text)[0]: i
+                   for i, _ in found if i.opcode == "fusion"}
+        writes = []
+        for i, where in found:
+            if i.opcode == "fusion":  # no chunk-sized temporary
+                assert not any(len(d) == 2 and d != (n, n) and min(d) >= n // 8
+                               for _, d in i.shapes), (i.name, i.shapes)
+            if i.opcode != "dynamic-update-slice" or i.shapes[0][1] != (n, n):
+                continue
+            assert where in fusions, f"standalone {i.name}"
+            comp = module.computations[where]
+            root = comp.root
+            outs = root.operands if root.opcode == "tuple" else (root.name,)
+            assert i.name in {o.lstrip("%") for o in outs}, (where, i.name)
+            adds = sum(x.opcode == "add" for x in comp.instructions)
+            assert adds >= 3, (where, adds)  # the 5-point stencil's sum
+            writes.append(fusions[where].name)
+        assert len(writes) == 2 * 20, writes
